@@ -2,84 +2,83 @@
 
 The paper's claim behind the ``sharded_rows`` backend is that Hessian rows
 are independent, so a single large-n HVP scales with the number of row
-shards.  This suite measures the engine-planned sharded_rows executable on
-fake host devices (``--xla_force_host_platform_device_count``, the same
-emulation tier-1's distributed tests use) across model-axis sizes, plus
-the single-device vmap_l2 baseline, and writes ``BENCH_pr4.json``.
+shards.  This suite measures the engine-planned sharded_rows executable
+across model-axis sizes, plus the single-device vmap_l2 baseline, and
+writes ``BENCH_pr4.json``.
 
-Faking runs every "device" on one CPU, so absolute rows/sec numbers are a
-correctness-path record of the schedule (like PR 3's interpret-mode pallas
-numbers), not a scaling measurement -- the mesh-shape sweep documents that
-every topology compiles and runs, and the JSON keeps per-shape timings for
-comparison against real multi-device runs.
-
-The measurement runs in a SUBPROCESS: only subprocesses fake device counts
-(dry-run rule), the orchestrating benchmark process keeps its real device.
+It runs in the calling process, on the mesh that ``jax.devices()`` gives:
+a chip belongs to one process, so the suite never starts a child.  Model
+axis sizes are the divisors of the device count (up to 8).  For a CPU
+rehearsal, fake host devices come from the environment set outside, e.g.
+``XLA_FLAGS=--xla_force_host_platform_device_count=8``; those all share
+one CPU, so their rows/sec record the schedule, not scaling.
 """
 
 from __future__ import annotations
 
 import json
 import os
-import subprocess
-import sys
-import textwrap
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
 
 from benchmarks.common import emit
+from repro import engine
+from repro.compat import make_mesh
+from repro.core import testfns
 
 MODEL_SIZES = (1, 2, 4, 8)
 NS = (64, 96)          # 96 = ragged on every model size but 1 with csize 8
 QUICK_NS = (32,)
 
-_WORKER = """
-import os
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count={devices} "
-    + os.environ.get("XLA_FLAGS", ""))
-import json, time
-import jax, jax.numpy as jnp, numpy as np
-from repro import engine
-from repro.core import testfns
-from repro.compat import make_mesh
 
-model_sizes = {model_sizes}
-ns = {ns}
-csize = {csize}
-records = []
-rng = np.random.RandomState(0)
-for n in ns:
-    f = testfns.FUNCTIONS["rosenbrock"](n)
-    a = jnp.asarray(rng.uniform(-2, 2, (n,)), jnp.float32)
-    v = jnp.asarray(rng.randn(n), jnp.float32)
-    for size in model_sizes:
-        for sym in (False, True):
-            if size == 1:
-                p = engine.plan(f, n, csize=csize, symmetric=sym)
+def _median_hvp_s(p, a, v, reps: int) -> float:
+    jax.block_until_ready(p.hvp(a, v))              # compile + warmup
+    times = []
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        jax.block_until_ready(p.hvp(a, v))
+        times.append(time.perf_counter() - t0)
+    return sorted(times)[len(times) // 2]
+
+
+def _mesh(size: int):
+    return make_mesh((len(jax.devices()) // size, size), ("data", "model"))
+
+
+def _device_label() -> str:
+    d = jax.devices()[0]
+    return f"{d.platform}:{d.device_kind} x{len(jax.devices())}"
+
+
+def _rows_records(ns, model_sizes, csize: int) -> list:
+    records = []
+    rng = np.random.RandomState(0)
+    for n in ns:
+        f = testfns.FUNCTIONS["rosenbrock"](n)
+        a = jnp.asarray(rng.uniform(-2, 2, (n,)), jnp.float32)
+        v = jnp.asarray(rng.randn(n), jnp.float32)
+        for size in model_sizes:
+            for sym in (False, True):
+                if size == 1:
+                    p = engine.plan(f, n, csize=csize, symmetric=sym)
+                else:
+                    p = engine.plan(f, n, csize=csize, mesh=_mesh(size),
+                                    symmetric=sym)
                 backend = p.backend_for("hvp")
-            else:
-                mesh = make_mesh(({devices} // size, size),
-                                 ("data", "model"))
-                p = engine.plan(f, n, csize=csize, mesh=mesh,
-                                symmetric=sym)
-                backend = p.backend_for("hvp")
-                assert backend == "sharded_rows", backend
-            jax.block_until_ready(p.hvp(a, v))      # compile + warmup
-            times = []
-            for _ in range(3):
-                t0 = time.perf_counter()
-                jax.block_until_ready(p.hvp(a, v))
-                times.append(time.perf_counter() - t0)
-            t = sorted(times)[len(times) // 2]
-            records.append({{
-                "n": n, "csize": csize, "model_axis_size": size,
-                "symmetric": sym, "backend": backend,
-                "mesh_shape": ("1 device" if size == 1 else
-                               str({devices} // size) + "x" + str(size)),
-                "hvp_s": round(t, 6),
-                "rows_per_sec": round(n / t, 1),
-            }})
-print("BENCH_JSON " + json.dumps(records))
-"""
+                assert size == 1 or backend == "sharded_rows", backend
+                t = _median_hvp_s(p, a, v, reps=3)
+                records.append({
+                    "n": n, "csize": csize, "model_axis_size": size,
+                    "symmetric": sym, "backend": backend,
+                    "mesh_shape": ("1 device" if size == 1 else
+                                   f"{len(jax.devices()) // size}x{size}"),
+                    "hvp_s": round(t, 6),
+                    "rows_per_sec": round(n / t, 1),
+                })
+    return records
 
 
 # PR 6: symmetric wall clock on the row-sharded backend -- the compacted
@@ -87,102 +86,73 @@ print("BENCH_JSON " + json.dumps(records))
 # devices serialize on one CPU, which makes them an honest TOTAL-WORK clock:
 # the masked block layout executes the full grid's cells even when half are
 # predicated away, so skipping shows up directly.
-_WORKER_PR6 = """
-import os
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count={devices} "
-    + os.environ.get("XLA_FLAGS", ""))
-import json, time
-import jax, jax.numpy as jnp, numpy as np
-from repro import engine
-from repro.core import testfns
-from repro.core.api import num_chunk_evals
-from repro.core.distributed import cyclic_layout, rows_per_shard
-from repro.compat import make_mesh
-
-ns = {ns}
-csize = {csize}
-size = {size}
-mesh = make_mesh(({devices} // size, size), ("data", "model"))
-records = []
-rng = np.random.RandomState(0)
-
-def clock(p, a, v):
-    jax.block_until_ready(p.hvp(a, v))
-    ts = []
-    for _ in range(5):
-        t0 = time.perf_counter()
-        jax.block_until_ready(p.hvp(a, v))
-        ts.append(time.perf_counter() - t0)
-    return sorted(ts)[len(ts) // 2]
-
-for n in ns:
-    f = testfns.FUNCTIONS["rosenbrock"](n)
-    a = jnp.asarray(rng.uniform(-2, 2, (n,)), jnp.float32)
-    v = jnp.asarray(rng.randn(n), jnp.float32)
-    variants = {{
-        "full": dict(symmetric=False),
-        "sym_block": dict(symmetric=True, row_layout="block"),
-        "sym_cyclic": dict(symmetric=True, row_layout="cyclic"),
-    }}
-    times = {{}}
-    for label, kw in variants.items():
-        p = engine.plan(f, n, csize=csize, mesh=mesh, **kw)
-        assert p.backend_for("hvp") == "sharded_rows"
-        times[label] = clock(p, a, v)
-    lay = cyclic_layout(n, csize, size)
-    grid_cells = size * rows_per_shard(n, size) * (-(-n // csize))
-    records.append({{
-        "n": n, "csize": csize, "model_axis_size": size,
-        "hvp_s": {{k: round(t, 6) for k, t in times.items()}},
-        "cells": {{"full": num_chunk_evals(n, csize, False),
-                   "sym_block_executed": grid_cells,
-                   "sym_cyclic_executed": size * lay.executed,
-                   "sym_kept": num_chunk_evals(n, csize, True)}},
-        "sym_cyclic_speedup_vs_full":
-            round(times["full"] / times["sym_cyclic"], 3),
-        "cyclic_speedup_vs_block":
-            round(times["sym_block"] / times["sym_cyclic"], 3),
-    }})
-print("BENCH_JSON " + json.dumps(records))
-"""
+def _pr6_records(ns, csize: int, size: int) -> list:
+    from repro.core.api import num_chunk_evals
+    from repro.core.distributed import cyclic_layout, rows_per_shard
+    mesh = _mesh(size)
+    records = []
+    rng = np.random.RandomState(0)
+    for n in ns:
+        f = testfns.FUNCTIONS["rosenbrock"](n)
+        a = jnp.asarray(rng.uniform(-2, 2, (n,)), jnp.float32)
+        v = jnp.asarray(rng.randn(n), jnp.float32)
+        variants = {
+            "full": dict(symmetric=False),
+            "sym_block": dict(symmetric=True, row_layout="block"),
+            "sym_cyclic": dict(symmetric=True, row_layout="cyclic"),
+        }
+        times = {}
+        for label, kw in variants.items():
+            p = engine.plan(f, n, csize=csize, mesh=mesh, **kw)
+            assert p.backend_for("hvp") == "sharded_rows"
+            times[label] = _median_hvp_s(p, a, v, reps=5)
+        lay = cyclic_layout(n, csize, size)
+        grid_cells = size * rows_per_shard(n, size) * (-(-n // csize))
+        records.append({
+            "n": n, "csize": csize, "model_axis_size": size,
+            "hvp_s": {k: round(t, 6) for k, t in times.items()},
+            "cells": {"full": num_chunk_evals(n, csize, False),
+                      "sym_block_executed": grid_cells,
+                      "sym_cyclic_executed": size * lay.executed,
+                      "sym_kept": num_chunk_evals(n, csize, True)},
+            "sym_cyclic_speedup_vs_full":
+                round(times["full"] / times["sym_cyclic"], 3),
+            "cyclic_speedup_vs_block":
+                round(times["sym_block"] / times["sym_cyclic"], 3),
+        })
+    return records
 
 
-def _run_worker(prog: str) -> list:
-    env = dict(os.environ)
-    root = os.path.join(os.path.dirname(__file__), "..")
-    env["PYTHONPATH"] = (os.path.join(root, "src") + os.pathsep
-                         + env.get("PYTHONPATH", ""))
-    out = subprocess.run([sys.executable, "-c", textwrap.dedent(prog)],
-                         env=env, capture_output=True, text=True,
-                         timeout=1800)
-    if out.returncode != 0:
-        raise RuntimeError(
-            f"distributed bench worker failed:\n{out.stdout}\n{out.stderr}")
-    line = [l for l in out.stdout.splitlines()
-            if l.startswith("BENCH_JSON ")][-1]
-    return json.loads(line[len("BENCH_JSON "):])
+def _model_sizes(wanted) -> tuple:
+    """The requested model-axis sizes that divide the device count."""
+    devices = len(jax.devices())
+    return tuple(s for s in wanted if s <= devices and devices % s == 0)
 
 
-def run_pr6(quick: bool = False, devices: int = 8, size: int = 4):
+def run_pr6(quick: bool = False, size: int = 4):
     """Symmetric wall-clock sweep for sharded_rows, merged into the
-    "distributed" section of BENCH_pr6.json."""
+    "distributed" section of BENCH_pr6.json.  Skipped where the devices
+    do not split into a ``size``-wide model axis."""
     from benchmarks.common import update_bench_json
+    if not _model_sizes((size,)):
+        emit("distributed/pr6_wallclock", "skipped",
+             f"{_device_label()}: no {size}-wide model axis")
+        return []
     ns = (32,) if quick else (48, 64)
-    records = _run_worker(_WORKER_PR6.format(
-        devices=devices, size=size, ns=repr(tuple(ns)), csize=4))
+    records = _pr6_records(ns, csize=4, size=size)
     for rec in records:
         emit(f"distributed/pr6_wallclock/n{rec['n']}",
              f"{rec['sym_cyclic_speedup_vs_full']}x vs full",
              f"cyclic-vs-block {rec['cyclic_speedup_vs_block']}x; cells "
              f"{rec['cells']['full']} -> {rec['cells']['sym_cyclic_executed']}"
              f" executed / {rec['cells']['sym_kept']} kept "
-             "(fake devices: total-work timing)")
+             f"({_device_label()})")
     payload = {
-        "note": ("fake host devices serialize on one CPU, so wall clock "
-                 "tracks TOTAL executed cells: the masked block layout "
-                 "pays for the dropped triangle, the cyclic layout skips "
-                 "it"),
+        "device": _device_label(),
+        "note": ("on fake host devices, which serialize on one CPU, wall "
+                 "clock tracks TOTAL executed cells: the masked block "
+                 "layout pays for the dropped triangle, the cyclic layout "
+                 "skips it"),
         "model_axis_size": size,
         "records": records,
     }
@@ -192,23 +162,8 @@ def run_pr6(quick: bool = False, devices: int = 8, size: int = 4):
     return records
 
 
-def run(ns=NS, model_sizes=MODEL_SIZES, csize=8, devices=8, out_path=None):
-    prog = _WORKER.format(devices=devices,
-                          model_sizes=repr(tuple(model_sizes)),
-                          ns=repr(tuple(ns)), csize=csize)
-    env = dict(os.environ)
-    root = os.path.join(os.path.dirname(__file__), "..")
-    env["PYTHONPATH"] = (os.path.join(root, "src") + os.pathsep
-                         + env.get("PYTHONPATH", ""))
-    out = subprocess.run([sys.executable, "-c", textwrap.dedent(prog)],
-                         env=env, capture_output=True, text=True,
-                         timeout=1800)
-    if out.returncode != 0:
-        raise RuntimeError(
-            f"distributed bench worker failed:\n{out.stdout}\n{out.stderr}")
-    line = [l for l in out.stdout.splitlines()
-            if l.startswith("BENCH_JSON ")][-1]
-    records = json.loads(line[len("BENCH_JSON "):])
+def run(ns=NS, model_sizes=MODEL_SIZES, csize=8, out_path=None):
+    records = _rows_records(ns, _model_sizes(model_sizes), csize)
 
     for rec in records:
         emit(f"distributed/rosenbrock/n{rec['n']}"
@@ -216,13 +171,13 @@ def run(ns=NS, model_sizes=MODEL_SIZES, csize=8, devices=8, out_path=None):
              f"/{'sym' if rec['symmetric'] else 'full'}/rows_per_sec",
              rec["rows_per_sec"],
              f"backend={rec['backend']}, {rec['hvp_s'] * 1e3:.2f} ms "
-             "(fake devices: correctness-path timing)")
+             f"({_device_label()})")
 
     payload = {
         "bench": "distributed_rows",
-        "devices": devices,
-        "note": ("fake host devices share one CPU; rows/sec documents the "
-                 "schedule across mesh shapes, not real scaling"),
+        "device": _device_label(),
+        "note": ("on fake host devices, which share one CPU, rows/sec "
+                 "documents the schedule across mesh shapes, not scaling"),
         "records": records,
     }
     path = out_path or os.environ.get("BENCH_PR4_OUT", "BENCH_pr4.json")

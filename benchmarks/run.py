@@ -45,6 +45,8 @@ def main() -> None:
     from contextlib import nullcontext
 
     from repro import obs
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     session = (obs.profile_session(args.profile) if args.profile
                else nullcontext())
     print("name,value,derived")

@@ -1,119 +1,22 @@
-"""JAX version-compatibility shims.
+"""Mesh construction with Auto axes.
 
-The repo targets the modern API surface (``jax.shard_map`` with
-``check_vma``, ``jax.make_mesh`` with ``axis_types``); older 0.4.x
-installs expose ``jax.experimental.shard_map`` with ``check_rep`` and a
-``make_mesh`` without axis types.  The wrappers here accept the modern
-keyword set and translate to whatever the installed JAX understands, so
-every call site (distributed CHESSFAD, MoE, pipeline, train steps, tests)
-has ONE place that knows about the renames.
-
-The shard_map shim is gated on the PARSED jax version, not
-try/except-at-import: the version thresholds below say exactly when each
-rename happened, and on a jax that already speaks the modern names the
-shim is a pure passthrough (asserted by tests/test_compat.py) -- dropping
-it when the container jax moves past 0.8 is deleting the ``else``
-branches, not untangling exception flow.
-
-  >= 0.6.0 : ``shard_map`` is public at ``jax.shard_map``
-             (older: ``jax.experimental.shard_map.shard_map``)
-  >= 0.7.0 : the replication-check keyword is ``check_vma``
-             (older: ``check_rep``)
-
-Re-verified 2026-08 against the container toolchain (jax 0.4.37): every
-legacy branch is the live one there -- ``jax.experimental.shard_map`` with
-``check_rep``, ``jax.make_mesh`` without ``axis_types``,
-``jax.sharding.AxisType`` absent -- and the modern branches are exercised
-by tests/test_compat.py through monkeypatched gates.  The old ``make_mesh``
-double-probe ("axis_types accepted but AxisType missing") was dead on every
-version either way (the keyword and the enum shipped together; a
-``make_mesh`` accepting ``axis_types`` with no enum to pass is not a real
-jax) and is now folded into the single import-time
-``MAKE_MESH_HAS_AXIS_TYPES`` gate.
+``jax.make_mesh`` makes Explicit axes by default.  The repository's
+shardings are written for Auto axes, where GSPMD propagates layouts
+through gathers and reshapes, so every mesh is built through
+``make_mesh`` here: it names ``AxisType.Auto`` for each axis unless the
+caller passes ``axis_types`` itself.  ``shard_map`` is ``jax.shard_map``.
 """
 
 from __future__ import annotations
 
-import inspect
-
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["shard_map", "make_mesh", "auto_axis_types", "jax_version",
-           "SHARD_MAP_IS_PUBLIC", "REP_CHECK_KW",
-           "MAKE_MESH_HAS_AXIS_TYPES"]
-
-
-def jax_version(version: str | None = None) -> tuple:
-    """The installed jax version as a comparable (major, minor, patch)
-    tuple; dev/rc suffixes are ignored."""
-    parts = []
-    for p in (version or jax.__version__).split(".")[:3]:
-        digits = ""
-        for ch in p:
-            if not ch.isdigit():
-                break
-            digits += ch
-        parts.append(int(digits or 0))
-    while len(parts) < 3:
-        parts.append(0)
-    return tuple(parts)
-
-
-_JAX = jax_version()
-
-# version gates (see module docstring); SHARD_MAP_IS_PUBLIC / REP_CHECK_KW
-# are exported so tests can assert the shim picked the right branch
-SHARD_MAP_IS_PUBLIC = _JAX >= (0, 6, 0)
-REP_CHECK_KW = "check_vma" if _JAX >= (0, 7, 0) else "check_rep"
-
-if SHARD_MAP_IS_PUBLIC:
-    _shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_MAKE_MESH_PARAMS = inspect.signature(jax.make_mesh).parameters
-
-# One import-time capability gate: the axis_types keyword and the AxisType
-# enum shipped together, so probing both collapses to a single constant
-# (on 0.4.37 both probes are False; see the module docstring).
-MAKE_MESH_HAS_AXIS_TYPES = (
-    "axis_types" in _MAKE_MESH_PARAMS
-    and getattr(jax.sharding, "AxisType", None) is not None)
-
-
-def auto_axis_types(n_axes: int):
-    """(AxisType.Auto,) * n_axes on jax versions that have axis types,
-    None otherwise."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return None
-    return (axis_type.Auto,) * n_axes
+__all__ = ["make_mesh"]
 
 
 def make_mesh(axis_shapes, axis_names, **kw):
-    """jax.make_mesh accepting ``axis_types`` on every jax version (the
-    keyword is dropped where unsupported; Auto is the legacy behavior)."""
-    if MAKE_MESH_HAS_AXIS_TYPES:
-        if kw.get("axis_types") is None:
-            kw["axis_types"] = auto_axis_types(len(tuple(axis_names)))
-    else:
-        kw.pop("axis_types", None)
+    """``jax.make_mesh`` with ``AxisType.Auto`` on every axis by default."""
+    if kw.get("axis_types") is None:
+        kw["axis_types"] = (AxisType.Auto,) * len(tuple(axis_names))
     return jax.make_mesh(axis_shapes, axis_names, **kw)
-
-
-def shard_map(f=None, *, mesh, in_specs, out_specs, check_vma=True, **kw):
-    """Drop-in for jax's shard_map, tolerant of the check_vma/check_rep
-    rename (same default, True, as stock jax).  Usable directly or via
-    functools.partial as a decorator.
-
-    On jax >= 0.7 this forwards ``check_vma`` under its own name -- a
-    no-op passthrough; on older versions the value travels as
-    ``check_rep``.  An explicit ``check_rep``/``check_vma`` in ``kw``
-    wins over the ``check_vma`` parameter."""
-    if REP_CHECK_KW not in kw:
-        kw[REP_CHECK_KW] = check_vma
-    if f is None:
-        return lambda fn: _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                     out_specs=out_specs, **kw)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)

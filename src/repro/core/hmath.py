@@ -184,12 +184,22 @@ def matvec_const(A, u):
 
     Linear maps act componentwise on all 2c+2 hDual slots -- this is the
     identity exploited by the fused hdual_linear kernel (DESIGN.md §3).
+    The chunk slots are contracted one lane at a time: inside the Pallas
+    kernel a tensordot over (n, blk_m, csize) needs a 3-D <-> 2-D reshape
+    that Mosaic cannot lower.  ``Precision.HIGHEST`` keeps float32
+    products on the TPU, whose default matmul rounds inputs to bfloat16.
     """
     if not isinstance(u, HDual):
-        return A @ u
-    return HDual(A @ u.val, A @ u.di,
-                 jnp.tensordot(A, u.dj, axes=([1], [0])),
-                 jnp.tensordot(A, u.dij, axes=([1], [0])))
+        return _mv(A, u)
+
+    def chunks(x):
+        return jnp.stack([_mv(A, x[..., l]) for l in range(u.csize)], -1)
+
+    return HDual(_mv(A, u.val), _mv(A, u.di), chunks(u.dj), chunks(u.dij))
+
+
+def _mv(A, x):
+    return jnp.matmul(A, x, precision=jax.lax.Precision.HIGHEST)
 
 
 def dot_const(u, w):

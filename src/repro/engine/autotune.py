@@ -501,7 +501,9 @@ def _combo_grid(fp: str, n, mm: int, symmetric: bool, backend: str,
     if pinned_blk_m is not None:
         blk_ms = [int(pinned_blk_m)]
     else:
-        blk_ms = [b for b in (4, 8, 16) if b <= mm] or [mm]
+        # block heights Mosaic takes (chess_hvp.block_rows): multiples of
+        # the sublane tile, or the whole probe batch when it is smaller
+        blk_ms = [b for b in (8, 16) if b <= mm] or [mm]
     combos = []
     for bk in backends:
         for c in (csizes if _csize_swept(bk, workload) else [argmin]):
@@ -660,6 +662,7 @@ def autotune(f, n, m=None, symmetric: bool = False,
                 run = lambda: p.hessian(probe_a)
             t = _time_once(run, reps=reps, deadline_s=rep_deadline_s)
         except Exception as e:   # a single infeasible candidate is fine
+            _raise_if_kernel_fault(bk)
             last_err = e
             continue
         if best is None or t < best.time_s:
@@ -676,6 +679,14 @@ def autotune(f, n, m=None, symmetric: bool = False,
     if persistable:
         _persist(skey, best)
     return best
+
+
+def _raise_if_kernel_fault(backend: str) -> None:
+    """Inside a candidate's ``except``: re-raise when the candidate was the
+    pallas kernel on the TPU.  There a Mosaic or compile error is a fault
+    to surface, never a slow configuration for vmap_l2 to quietly win."""
+    if backend == "pallas" and jax.default_backend() == "tpu":
+        raise
 
 
 def _feasible(cfg: TunedConfig, workload: str) -> bool:
@@ -1019,6 +1030,7 @@ def autotune_buckets(f, n: int, buckets, *, symmetric: bool = False,
                     t = _time_once(run, reps=reps,
                                    deadline_s=rep_deadline_s)
                 except Exception as e:
+                    _raise_if_kernel_fault(bk)
                     last_err = e
                     continue
                 us_pp = t / b * 1e6

@@ -141,7 +141,11 @@ def _ensure_builtin_backends() -> None:
     try:
         import repro.kernels.ops  # noqa: F401  (pallas, optional layer)
     except Exception as e:  # pragma: no cover - pallas unavailable
-        # optional, but never silent: on TPU this is the production path
+        # on TPU this is the production path: a missing kernel layer is an
+        # error there, and only a warning where pallas is a parity path
+        import jax
+        if jax.default_backend() == "tpu":
+            raise
         import warnings
         warnings.warn(f"pallas backend unavailable "
                       f"(repro.kernels.ops failed to import): {e!r}")

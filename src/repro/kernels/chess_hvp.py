@@ -43,7 +43,9 @@ v2's lifted preconditions are kept verbatim:
   m % blk_m       : the wrapper pads the instance axis by edge replication
                     (padding rows stay inside f's domain; see
                     engine.pad_rows for the same rationale) and slices the
-                    padding back off.  Any ``m >= 1`` is served.
+                    padding back off.  Any ``m >= 1`` is served; the block
+                    height is a multiple of 8 or all of ``m``
+                    (``block_rows``), the shapes Mosaic accepts.
 
 The symmetric masks are CHUNK-granular, matching ``core.api.hvp_impl``
 (vmap_l2) bit-for-bit in which H entries feed which output slot: a cell
@@ -73,7 +75,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.hdual import HDual
 
-__all__ = ["chess_hvp_pallas", "kernel_grid"]
+__all__ = ["chess_hvp_pallas", "kernel_grid", "block_rows",
+           "resolve_interpret"]
 
 
 def kernel_grid(m: int, n: int, csize: int, blk_m: int,
@@ -87,9 +90,37 @@ def kernel_grid(m: int, n: int, csize: int, blk_m: int,
     and the roofline report assert against ``core.api.num_chunk_evals``.
     """
     from repro.core.api import num_chunk_evals
-    blk_m = max(1, min(blk_m, m))
+    blk_m = block_rows(m, blk_m)
     m_pad = -(-m // blk_m) * blk_m
     return (m_pad // blk_m, num_chunk_evals(n, csize, symmetric))
+
+
+SUBLANES = 8      # f32 sublane tile: Mosaic's unit for a block's row count
+
+
+def block_rows(m: int, blk_m: int) -> int:
+    """The instance-block height the kernel launches with.
+
+    Mosaic takes a block whose row count is a multiple of the sublane tile
+    or the whole (padded) instance axis, so a ``blk_m`` of at least ``m``
+    becomes ``m`` (one block) and any other value is rounded up to a
+    multiple of 8.
+    """
+    if blk_m < 1:
+        raise ValueError(f"blk_m={blk_m} must be >= 1")
+    return min(m, -(-blk_m // SUBLANES) * SUBLANES)
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """Interpret mode for a Pallas call: the default off the TPU, refused
+    on it, where every kernel compiles with Mosaic."""
+    on_tpu = jax.default_backend() == "tpu"
+    if interpret is None:
+        return not on_tpu
+    if interpret and on_tpu:
+        raise ValueError("Pallas interpret mode is refused on the TPU: "
+                         "kernels there compile with Mosaic")
+    return bool(interpret)
 
 
 def _kernel(rows_ref, starts_ref, a_ref, v_ref, *rest, f, n, n_pad, csize,
@@ -106,11 +137,12 @@ def _kernel(rows_ref, starts_ref, a_ref, v_ref, *rest, f, n, n_pad, csize,
 
     k2 = jax.lax.broadcasted_iota(jnp.int32, (n, blk_m), 0)
     di = (k2 == i).astype(jnp.float32)
-    k3 = jax.lax.broadcasted_iota(jnp.int32, (n, blk_m, csize), 0)
-    l3 = jax.lax.broadcasted_iota(jnp.int32, (n, blk_m, csize), 2)
+    l2 = jax.lax.broadcasted_iota(jnp.int32, (blk_m, csize), 1)
     # ragged tail: columns cstart+l >= n match no variable -> zero dj
-    # lanes -> zero dij lanes; the masks below drop them explicitly.
-    dj = (k3 == cstart + l3).astype(jnp.float32)
+    # lanes -> zero dij lanes; the masks below drop them explicitly.  Built
+    # from 2-D iotas: Mosaic aborts on leading-axis slices (f's x[1:]) of
+    # a 3-D iota once blk_m spans more than one sublane tile.
+    dj = (k2[:, :, None] == cstart + l2[None, :, :]).astype(jnp.float32)
     dij = jnp.zeros((n, blk_m, csize), jnp.float32)
 
     y = HDual(at, di, dj, dij)
@@ -118,33 +150,37 @@ def _kernel(rows_ref, starts_ref, a_ref, v_ref, *rest, f, n, n_pad, csize,
 
     v = v_ref[...].astype(jnp.float32)         # (blk_m, n_pad), zero-padded
     cols = cstart + jax.lax.broadcasted_iota(jnp.int32, (blk_m, csize), 1)
-    vc = jnp.take_along_axis(v, cols, axis=1)            # v[:, cstart:+csize]
     valid = cols < n
+    # chunk -> padded-column one-hot: the lane gathers v[:, cstart:+csize]
+    # and v[:, i] are exact one-hot where/sum reductions, because Mosaic
+    # has no lowering for a dynamic lane gather (take_along_axis)
+    lj = jax.lax.broadcasted_iota(jnp.int32, (csize, n_pad), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (csize, n_pad), 1)
+    sel = jj == cstart + lj                              # (csize, n_pad)
+    vc = jnp.sum(jnp.where(sel[None, :, :], v[:, None, :], 0.0), axis=2)
     # direct: H[i, j] * v[j] -> r[i] for every valid column of the cell --
     # the compacted symmetric enumeration only ever reaches this kernel
     # with at-or-right-of-diagonal cells, and the diagonal-block cell
     # contributes ALL its columns directly (vmap_l2 semantics).
-    contrib = jnp.sum(jnp.where(valid, r.dij * vc, 0.0), axis=1)
+    contrib = jnp.sum(jnp.where(valid, r.dij * vc, 0.0), axis=1,
+                      keepdims=True)                     # (blk_m, 1)
 
-    rowsel = (jax.lax.broadcasted_iota(jnp.int32, (blk_m, n_pad), 1)
-              == i).astype(jnp.float32)
-    add = contrib[:, None] * rowsel                      # (blk_m, n_pad)
+    rowsel = jax.lax.broadcasted_iota(jnp.int32, (blk_m, n_pad), 1) == i
+    add = jnp.where(rowsel, contrib, 0.0)               # (blk_m, n_pad)
 
     if symmetric:
         # mirror: a cell strictly right of the diagonal block contributes
         # H[i, j] * v[i] to r[j] for its whole chunk (Alg. 8 lines 12-15;
         # chunk-granular like vmap_l2 -- the condition is uniform over the
-        # cell because a cell spans exactly one chunk).  Scatter through a
+        # cell because a cell spans exactly one chunk).  Scatter through the
         # chunk->row one-hot so the write stays a dense VPU op on the
         # resident output block.
         mirrors = cstart > (i // csize) * csize          # scalar, traced
-        vi = jnp.take_along_axis(
-            v, jnp.full((blk_m, 1), i, jnp.int32), axis=1)[:, 0]
-        mvals = jnp.where(valid & mirrors, r.dij, 0.0) * vi[:, None]
-        lj = jax.lax.broadcasted_iota(jnp.int32, (csize, n_pad), 0)
-        jj = jax.lax.broadcasted_iota(jnp.int32, (csize, n_pad), 1)
-        sel = (jj == cstart + lj).astype(jnp.float32)
-        add = add + jnp.sum(mvals[:, :, None] * sel[None, :, :], axis=1)
+        vi = jnp.sum(jnp.where(rowsel, v, 0.0), axis=1,
+                     keepdims=True)                      # v[:, i]
+        mvals = jnp.where(valid & mirrors, r.dij, 0.0) * vi
+        add = add + jnp.sum(
+            jnp.where(sel[None, :, :], mvals[:, :, None], 0.0), axis=1)
 
     @pl.when(first)
     def _init():
@@ -157,7 +193,8 @@ def _kernel(rows_ref, starts_ref, a_ref, v_ref, *rest, f, n, n_pad, csize,
 
 def chess_hvp_pallas(f: Callable, A, V, csize: int, *,
                      consts: Sequence = (), blk_m: int = 8,
-                     symmetric: bool = False, interpret: bool = True):
+                     symmetric: bool = False,
+                     interpret: bool | None = None):
     """Batched HVP out[m] = H_f(A[m]) @ V[m] via the L2 grid schedule.
 
     A, V: (m, n). Returns (m, n).  Serves ANY (m, n, csize) with m >= 1 and
@@ -168,14 +205,17 @@ def chess_hvp_pallas(f: Callable, A, V, csize: int, *,
     at-or-right-of-diagonal cells exist in the trip count (v3 -- no
     predicated ghosts), and strictly-right cells are mirrored through the
     VMEM output accumulator.  ``kernel_grid(m, n, csize, blk_m, symmetric)``
-    is the exact launch shape.
+    is the exact launch shape; ``block_rows`` says which block height a
+    requested ``blk_m`` becomes.  ``interpret`` follows
+    ``resolve_interpret``.
     """
     from repro.core.api import chunk_pairs
 
     m, n = A.shape
     assert V.shape == (m, n)
     assert m >= 1 and csize >= 1, (m, csize)
-    blk_m = max(1, min(blk_m, m))
+    interpret = resolve_interpret(interpret)
+    blk_m = block_rows(m, blk_m)
     nchunk = -(-n // csize)                    # ceil-div chunk grid
     n_pad = nchunk * csize
     m_pad = -(-m // blk_m) * blk_m

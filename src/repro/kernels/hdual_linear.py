@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.chess_hvp import resolve_interpret
+
 __all__ = ["hdual_linear_pallas"]
 
 
@@ -42,9 +44,10 @@ def _kernel(x_ref, w_ref, o_ref, *, acc_dtype):
 
 
 def hdual_linear_pallas(x, w, *, bt: int = 128, bo: int = 128, bk: int = 128,
-                        interpret: bool = True):
+                        interpret: bool | None = None):
     """x: (K2, T, din) stacked hDual components; w: (din, dout).
-    Returns (K2, T, dout). Tiles clamp to the actual dims."""
+    Returns (K2, T, dout). Tiles clamp to the actual dims.  ``interpret``
+    follows ``chess_hvp.resolve_interpret``."""
     K2, T, din = x.shape
     dout = w.shape[1]
     assert w.shape[0] == din
@@ -62,5 +65,5 @@ def hdual_linear_pallas(x, w, *, bt: int = 128, bo: int = 128, bk: int = 128,
         ],
         out_specs=pl.BlockSpec((K2, bt, bo), lambda t, o, k: (0, t, o)),
         out_shape=jax.ShapeDtypeStruct((K2, T, dout), x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, w)

@@ -2,8 +2,9 @@
 backend registration.
 
 ``interpret`` defaults to True off-TPU (the kernels are TPU-target; CPU runs
-them through the Pallas interpreter for correctness), and to False on TPU
-where Mosaic compiles them for real.
+them through the Pallas interpreter for correctness).  On TPU Mosaic
+compiles them, and asking for the interpreter there is an error
+(``chess_hvp.resolve_interpret``).
 
 Kernel-compatible forms of a target function are discovered via the
 ``pallas_fn`` / ``pallas_consts`` attributes (see testfns.make_fletcher_
@@ -21,15 +22,10 @@ import jax.numpy as jnp
 
 from repro.core import testfns
 from repro.engine.registry import BackendSpec, register_backend
-from repro.kernels.chess_hvp import chess_hvp_pallas
+from repro.kernels.chess_hvp import chess_hvp_pallas, resolve_interpret
 from repro.kernels.hdual_linear import hdual_linear_pallas
 
-__all__ = ["chess_hvp", "hdual_linear", "hdual_linear_apply",
-           "default_interpret", "kernel_form"]
-
-
-def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+__all__ = ["chess_hvp", "hdual_linear", "hdual_linear_apply", "kernel_form"]
 
 
 def kernel_form(f):
@@ -57,17 +53,15 @@ def _pallas_supports(plan, workload):
 
 def _pallas_make(plan, workload):
     kernel_f, consts = kernel_form(plan.f)
-    interpret = plan.opt("interpret")
-    if interpret is None:
-        interpret = default_interpret()
-    blk_m_opt = plan.opt("blk_m")
+    # resolved once per executable: a TPU plan asking for the interpreter
+    # fails before anything is traced
+    interpret = resolve_interpret(plan.opt("interpret"))
+    # the wrapper pads m up to a block multiple, so blk_m is purely a
+    # tuning dial (the joint autotuner sweeps it); default to the sublane
+    # tile, which block_rows caps at m so tiny batches don't pad 8x
+    blk_m = plan.opt("blk_m") or 8
 
     def run(A, V):
-        m = A.shape[0]                          # static at trace time
-        # the wrapper pads m up to a blk_m multiple, so blk_m is purely a
-        # tuning dial (the joint autotuner sweeps it); default to the
-        # sublane width, capped so tiny batches don't pad 8x
-        blk_m = blk_m_opt or min(8, m)
         return chess_hvp_pallas(kernel_f, A, V, plan.csize, consts=consts,
                                 blk_m=blk_m, symmetric=plan.symmetric,
                                 interpret=interpret)
@@ -81,8 +75,8 @@ register_backend(BackendSpec(
     # interpret mode it is a correctness path only, so auto never picks it
     priority=40 if jax.default_backend() == "tpu" else -5,
     supports=_pallas_supports,
-    doc="Fig. 2 L2 grid kernel v2 (symmetric + ragged; Pallas; "
-        "interpret=True off-TPU)"))
+    doc="Fig. 2 L2 grid kernel v2 (symmetric + ragged; Pallas; Mosaic on "
+        "TPU, interpreter elsewhere)"))
 
 
 @partial(jax.jit, static_argnames=("function", "csize", "blk_m", "symmetric",
@@ -93,8 +87,6 @@ def chess_hvp(A, V, *, function: str = "rosenbrock", csize: int = 4,
     """Batched HVP on one of the paper's test-function families.
 
     A, V: (m, n) -> (m, n)."""
-    if interpret is None:
-        interpret = default_interpret()
     n = A.shape[-1]
     f, consts = _fn_and_consts(function, n)
     return chess_hvp_pallas(f, A, V, csize, consts=consts, blk_m=blk_m,
@@ -105,8 +97,6 @@ def chess_hvp(A, V, *, function: str = "rosenbrock", csize: int = 4,
 def hdual_linear(x, w, *, bt: int = 128, bo: int = 128, bk: int = 128,
                  interpret: bool | None = None):
     """Fused hDual component matmul: x (K2, T, din) @ w (din, dout)."""
-    if interpret is None:
-        interpret = default_interpret()
     return hdual_linear_pallas(x, w, bt=bt, bo=bo, bk=bk,
                                interpret=interpret)
 

@@ -36,7 +36,8 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import SHAPES, all_cells, get_config
-from repro.launch.hlo_analysis import parse_collectives, roofline_terms
+from repro.launch.hlo_analysis import (parse_collectives, peaks_for,
+                                       roofline_terms)
 from repro.launch.mesh import make_production_mesh
 from repro.models import model as model_lib
 from repro.models.params import abstract_params, param_specs
@@ -44,6 +45,8 @@ from repro.optim import adamw
 from repro.optim.schedule import warmup_cosine
 from repro.parallel.sharding import (ACTIVATION_RULES, batch_spec, spec_for)
 from repro.training.steps import TrainState, make_train_step
+
+TARGET_DEVICE_KIND = "TPU v5 lite"      # the pod make_production_mesh models
 
 ARTIFACT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                             "artifacts", "dryrun")
@@ -271,7 +274,10 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool = False,
             # the once-per-step optimizer update; noted in §Perf)
             total_vec = total_vec * accum
         flops, bytes_, wire = (float(max(x, 0.0)) for x in total_vec)
-        terms = roofline_terms(flops, bytes_, wire)
+        # the production mesh is a v5e pod: project onto its peaks, not
+        # onto the host devices the dry run compiles for
+        terms = roofline_terms(flops, bytes_, wire,
+                               peaks_for(TARGET_DEVICE_KIND))
 
         mem_rec = {}
         if mem is not None:

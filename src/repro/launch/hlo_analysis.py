@@ -12,10 +12,10 @@ op, the bytes each device puts on the wire under a ring model:
 
 g = replica-group size parsed from the op's replica_groups / device list.
 
-Roofline terms (EXPERIMENTS.md §Roofline), TPU v5e constants:
-  compute   = FLOPs_per_device / 197e12            [s]
-  memory    = bytes_per_device / 819e9             [s]
-  collective= wire_bytes_per_device / 50e9         [s]  (per-link ICI)
+Roofline terms, with the peaks of the device the program runs on:
+  compute   = FLOPs_per_device / peak FLOP/s             [s]
+  memory    = bytes_per_device / peak HBM bytes/s        [s]
+  collective= wire_bytes_per_device / per-link ICI bytes/s [s]
 """
 
 from __future__ import annotations
@@ -24,11 +24,35 @@ import re
 from dataclasses import dataclass, field
 
 __all__ = ["CollectiveStats", "parse_collectives", "roofline_terms",
-           "PEAK_FLOPS", "HBM_BW", "ICI_BW"]
+           "DevicePeaks", "PEAKS", "peaks_for"]
 
-PEAK_FLOPS = 197e12      # bf16 FLOP/s per v5e chip
-HBM_BW = 819e9           # B/s per chip
-ICI_BW = 50e9            # B/s per ICI link
+
+@dataclass(frozen=True)
+class DevicePeaks:
+    flops: float          # FLOP/s per chip (bf16 MXU)
+    hbm_bw: float         # HBM bytes/s per chip
+    ici_bw: float         # bytes/s per ICI link
+    source: str
+
+
+# Keyed by ``jax.Device.device_kind``.  A device missing here has no
+# roofline: ``peaks_for`` raises rather than borrowing another chip's.
+PEAKS = {
+    "TPU v5 lite": DevicePeaks(
+        flops=197e12, hbm_bw=819e9,
+        ici_bw=1600e9 / 8 / 4,      # 1,600 Gbit/s over the chip's 4 links
+        source='Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, '
+               '819 GB/s HBM, 1,600 Gbit/s ICI per chip'),
+}
+
+
+def peaks_for(device_kind: str) -> DevicePeaks:
+    """The published peaks of ``device_kind``; unknown kinds are an error."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -117,10 +141,10 @@ def parse_collectives(hlo_text: str, n_devices: int) -> CollectiveStats:
 
 
 def roofline_terms(flops_per_dev: float, bytes_per_dev: float,
-                   wire_bytes_per_dev: float) -> dict:
-    t_c = flops_per_dev / PEAK_FLOPS
-    t_m = bytes_per_dev / HBM_BW
-    t_n = wire_bytes_per_dev / ICI_BW
+                   wire_bytes_per_dev: float, peaks: DevicePeaks) -> dict:
+    t_c = flops_per_dev / peaks.flops
+    t_m = bytes_per_dev / peaks.hbm_bw
+    t_n = wire_bytes_per_dev / peaks.ici_bw
     dom = max(("compute", t_c), ("memory", t_m), ("collective", t_n),
               key=lambda kv: kv[1])
     total = max(t_c, t_m, t_n)

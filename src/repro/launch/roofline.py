@@ -1,7 +1,8 @@
 """Roofline report: LLM dry-run cells AND the curvature backends.
 
 Default mode reads artifacts/dryrun/*.json and renders the per-cell
-three-term table (EXPERIMENTS.md §Roofline):
+three-term table (``hlo_analysis.roofline_terms`` with the v5e peaks the
+dry run's production pod targets):
 
   compute    = HLO_FLOPs_per_device / 197 TFLOP/s
   memory     = HLO_bytes_per_device / 819 GB/s
@@ -15,9 +16,9 @@ directly: for each (backend, schedule) it compiles the batched-HVP
 executable, reads HLO FLOPs/bytes from ``compiled.cost_analysis()``, times
 the executable, and reports
 
-  pct_roofline   = 100 * roofline_lower_bound / measured  (model peaks --
-                   v5e constants by default, overridable; on a CPU runner
-                   the absolute % is nominal but comparable across rows)
+  pct_roofline   = 100 * roofline_lower_bound / measured, with the peaks
+                   of the TPU it ran on (``hlo_analysis.peaks_for``); off
+                   the chip it is "not measured"
   cells_executed = the schedule's static tangent-sweep count (the pallas
                    launch grid / vmap cell enumeration / cyclic sharded
                    cell lists)
@@ -143,8 +144,7 @@ def _executed_cells(backend: str, m: int, n: int, csize: int, blk_m: int,
     return num_chunk_evals(n, csize, symmetric)
 
 
-def curvature_records(quick: bool = False, peak_flops: float | None = None,
-                      peak_bw: float | None = None) -> list[dict]:
+def curvature_records(quick: bool = False) -> list[dict]:
     """Measure every curvature backend on both schedules; one record per
     (backend, schedule) plus a static accounting row for the cyclic
     sharded_rows layout (its wall clock needs a multi-device mesh; its
@@ -157,10 +157,12 @@ def curvature_records(quick: bool = False, peak_flops: float | None = None,
     from repro.core import testfns
     from repro.core.api import num_chunk_evals
     from repro.core.distributed import cyclic_layout
-    from .hlo_analysis import HBM_BW, PEAK_FLOPS, roofline_terms
+    from .hlo_analysis import peaks_for, roofline_terms
 
-    pf = peak_flops or PEAK_FLOPS
-    bw = peak_bw or HBM_BW
+    dev = jax.devices()[0]
+    # a roofline share is a device metric: only a TPU's own peaks apply,
+    # and only to a time measured on it
+    peaks = peaks_for(dev.device_kind) if dev.platform == "tpu" else None
     blk_m = 8
     # pallas runs in interpret mode on CPU runners: keep its cell small
     configs = ([("vmap_l2", 16, 24, 4), ("pallas", 8, 8, 4)] if quick else
@@ -177,19 +179,23 @@ def curvature_records(quick: bool = False, peak_flops: float | None = None,
             run = p.executable("batched_hvp")
             flops, nbytes = _hlo_cost(run, A, V)
             t = _median_time(lambda r=run: r(A, V))
-            terms = roofline_terms(flops, nbytes, 0.0)
-            # the bound itself with the (possibly overridden) peaks
-            bound = max(flops / pf, nbytes / bw)
+            bound = bound_term = pct = None
+            if peaks is not None:
+                terms = roofline_terms(flops, nbytes, 0.0, peaks)
+                bound = terms["step_time_lower_bound_s"]
+                bound_term = terms["bound"]
+                pct = 100.0 * bound / t
             recs.append({
                 "backend": backend, "schedule": "sym" if sym else "full",
                 "m": m, "n": n, "csize": csize,
+                "device": f"{dev.platform}:{dev.device_kind}",
                 "cells_executed": _executed_cells(backend, m, n, csize,
                                                   blk_m, sym),
                 "cells_min": num_chunk_evals(n, csize, sym),
                 "flops": flops, "bytes": nbytes,
                 "measured_s": t, "bound_s": bound,
-                "pct_roofline": 100.0 * bound / t if t > 0 else 0.0,
-                "bound_term": terms["bound"],
+                "pct_roofline": pct,
+                "bound_term": bound_term,
                 "status": "measured",
             })
     # cyclic sharded_rows: static sweep accounting (host-side layout); the
@@ -240,6 +246,14 @@ def _sweep_gate(recs: list[dict]) -> list[str]:
     return failures
 
 
+def _fmt_pct(r: dict) -> str:
+    if r.get("status") != "measured":
+        return "-"
+    if r.get("pct_roofline") is None:
+        return "not measured"          # timed off the chip: no roofline
+    return f"{r['pct_roofline']:.2f}"
+
+
 def render_curvature(recs: list[dict], md: bool = False) -> str:
     hdr = ["backend", "sched", "n", "csize", "cells", "min", "flops",
            "measured", "bound", "%roof"]
@@ -251,7 +265,7 @@ def render_curvature(recs: list[dict], md: bool = False) -> str:
             f"{r['flops']:.2e}" if r.get("flops") else "-",
             _fmt_t(r["measured_s"]) if r.get("measured_s") else "-",
             _fmt_t(r["bound_s"]) if r.get("bound_s") else "-",
-            f"{r['pct_roofline']:.2f}" if r.get("pct_roofline") else "-",
+            _fmt_pct(r),
         ])
     widths = [max(len(str(row[i])) for row in rows + [hdr])
               for i in range(len(hdr))]

@@ -218,6 +218,8 @@ def main():
     if args.tuned_env == "apply":
         apply_tuned_env()       # no return on the exec path
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.no_obs:
         obs.disable()
     else:

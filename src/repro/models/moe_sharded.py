@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 
 from repro.models.common import silu
 from repro.models.moe import moe_block, router_topk

@@ -11,9 +11,10 @@ Third observability pillar (docs/observability.md).  Two pieces:
     annotation context manager is never even constructed outside a
     capture session.
 
-jax is imported lazily and failures degrade to no-ops: the obs package
-stays dependency-free, and profiling on hosts without a working
-profiler plugin silently does nothing rather than breaking serving.
+jax is imported lazily, so the obs package stays dependency-free until
+a capture is asked for.  A capture that cannot start raises: a run that
+was asked for a trace and silently produced none would be read as
+measured.
 """
 
 from __future__ import annotations
@@ -38,36 +39,29 @@ def profile_session(log_dir: str, *, create_perfetto_link: bool = False):
     """Capture a jax profiler trace for the enclosed block into log_dir.
 
     Nested/concurrent sessions are rejected (the jax profiler is a
-    process-global singleton).  If jax or its profiler is unavailable
-    the block still runs, unprofiled.
+    process-global singleton), and so is a trace that fails to start.
     """
     global _active
-    try:
-        from jax import profiler as _jp
-    except Exception:
-        yield None
-        return
+    from jax import profiler as _jp
     with _lock:
         if _active:
             raise RuntimeError("a profile_session is already active")
         _active = True
-    started = False
     try:
-        try:
-            _jp.start_trace(str(log_dir),
-                            create_perfetto_link=create_perfetto_link)
-            started = True
-        except Exception:
-            pass
-        yield log_dir if started else None
-    finally:
-        if started:
-            try:
-                _jp.stop_trace()
-            except Exception:
-                pass
+        _jp.start_trace(str(log_dir),
+                        create_perfetto_link=create_perfetto_link)
+    except BaseException:
         with _lock:
             _active = False
+        raise
+    try:
+        yield log_dir
+    finally:
+        try:
+            _jp.stop_trace()
+        finally:
+            with _lock:
+                _active = False
 
 
 def annotate(name: str):

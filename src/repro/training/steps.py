@@ -114,7 +114,7 @@ def make_shard_map_train_step(cfg, mesh: Mesh, optimizer, *,
     Used by the cross-pod-compression dry-run variant and the distributed
     tests; the GSPMD step remains the production default.
     """
-    from repro.compat import shard_map
+    from jax import shard_map
 
     loss_fn = loss_fn or (lambda p, b: model_lib.loss_fn(p, cfg, b, None))
     axes = data_axes(mesh)

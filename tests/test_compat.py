@@ -1,117 +1,32 @@
-"""repro.compat version-gated shims: the shard_map wrapper must pick its
-module location and replication-check keyword from the PARSED jax version
-(no try/except-at-import), and be a no-op passthrough on versions that
-already accept the modern names."""
+"""repro.compat.make_mesh: Auto axes unless the caller names others, and
+meshes that ``jax.shard_map`` runs on."""
 
 import jax
 import numpy as np
-import pytest
+from jax.sharding import AxisType
 
 from repro import compat
 
 
-def test_jax_version_parsing():
-    assert compat.jax_version("0.4.37") == (0, 4, 37)
-    assert compat.jax_version("0.8.0") == (0, 8, 0)
-    assert compat.jax_version("0.8") == (0, 8, 0)
-    assert compat.jax_version("0.7.1.dev20250101") == (0, 7, 1)
-    assert compat.jax_version("0.8.0rc1") == (0, 8, 0)
-    # tuple comparison is the guard the shims run on
-    assert compat.jax_version("0.8.0") >= (0, 7, 0)
-    assert not compat.jax_version("0.4.37") >= (0, 6, 0)
+def test_make_mesh_axes_are_auto():
+    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    assert mesh.axis_names == ("data", "model")
+    assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
 
 
-def test_version_gates_match_installed_jax():
-    """The branch constants must agree with an independent recomputation
-    from the installed version -- the gate is the version, nothing else."""
-    v = compat.jax_version()
-    assert compat.SHARD_MAP_IS_PUBLIC == (v >= (0, 6, 0))
-    assert compat.REP_CHECK_KW == ("check_vma" if v >= (0, 7, 0)
-                                   else "check_rep")
-    # the chosen symbol must be importable from the gated location
-    if compat.SHARD_MAP_IS_PUBLIC:
-        assert compat._shard_map is jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as legacy
-        assert compat._shard_map is legacy
-
-
-def test_make_mesh_gate_matches_installed_jax():
-    """MAKE_MESH_HAS_AXIS_TYPES must equal an independent re-probe of both
-    capabilities (the keyword and the enum ship together -- the collapsed
-    single gate is exactly their conjunction)."""
-    import inspect
-    has_kw = "axis_types" in inspect.signature(jax.make_mesh).parameters
-    has_enum = getattr(jax.sharding, "AxisType", None) is not None
-    assert compat.MAKE_MESH_HAS_AXIS_TYPES == (has_kw and has_enum)
-    # auto_axis_types agrees with the enum probe
-    if has_enum:
-        types = compat.auto_axis_types(2)
-        assert types == (jax.sharding.AxisType.Auto,) * 2
-    else:
-        assert compat.auto_axis_types(2) is None
-
-
-def test_make_mesh_drops_axis_types_where_unsupported():
-    """On a jax without the axis-types capability the shim must silently
-    drop even an EXPLICIT axis_types argument (legacy Auto behavior); on a
-    modern jax it must fill in AxisType.Auto per axis."""
-    if not compat.MAKE_MESH_HAS_AXIS_TYPES:
-        # object() would explode inside jax.make_mesh if forwarded
-        mesh = compat.make_mesh((1,), ("data",), axis_types=object())
-        assert mesh.axis_names == ("data",)
-    else:
-        mesh = compat.make_mesh((1,), ("data",))
-        assert mesh.axis_names == ("data",)
-
-
-def _capture_kwargs(monkeypatch):
-    seen = {}
-
-    def fake(f, *, mesh, in_specs, out_specs, **kw):
-        seen.update(kw)
-        return f
-
-    monkeypatch.setattr(compat, "_shard_map", fake)
-    return seen
-
-
-def test_shim_translates_to_check_rep_on_legacy(monkeypatch):
-    seen = _capture_kwargs(monkeypatch)
-    monkeypatch.setattr(compat, "REP_CHECK_KW", "check_rep")
-    compat.shard_map(lambda x: x, mesh=None, in_specs=(), out_specs=(),
-                     check_vma=False)
-    assert seen == {"check_rep": False}
-
-
-def test_shim_is_noop_passthrough_on_modern(monkeypatch):
-    """On versions that already accept check_vma the shim forwards the
-    keyword UNDER ITS OWN NAME -- no rename, no extra keywords."""
-    seen = _capture_kwargs(monkeypatch)
-    monkeypatch.setattr(compat, "REP_CHECK_KW", "check_vma")
-    compat.shard_map(lambda x: x, mesh=None, in_specs=(), out_specs=(),
-                     check_vma=False)
-    assert seen == {"check_vma": False}
-    seen.clear()
-    compat.shard_map(lambda x: x, mesh=None, in_specs=(), out_specs=())
-    assert seen == {"check_vma": True}     # stock-jax default preserved
-
-
-def test_explicit_kw_wins_over_parameter(monkeypatch):
-    seen = _capture_kwargs(monkeypatch)
-    compat.shard_map(lambda x: x, mesh=None, in_specs=(), out_specs=(),
-                     **{compat.REP_CHECK_KW: False})
-    assert seen == {compat.REP_CHECK_KW: False}
+def test_make_mesh_keeps_explicit_axis_types():
+    mesh = compat.make_mesh((1,), ("data",),
+                            axis_types=(AxisType.Explicit,))
+    assert mesh.axis_types == (AxisType.Explicit,)
 
 
 def test_shim_executes_on_installed_jax():
-    """End-to-end on whatever jax is installed: the translated keyword
-    must be accepted and the wrapper usable as a decorator factory."""
+    """jax.shard_map over a compat mesh, used as a decorator factory."""
     from functools import partial
     from jax.sharding import PartitionSpec as P
     mesh = compat.make_mesh((1,), ("data",))
 
-    @partial(compat.shard_map, mesh=mesh, in_specs=P(), out_specs=P(),
+    @partial(jax.shard_map, mesh=mesh, in_specs=P(), out_specs=P(),
              check_vma=False)
     def double(x):
         return x * 2.0

@@ -36,7 +36,7 @@ def test_hierarchical_grad_sync_compression():
     run_with_fake_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from functools import partial
-        from repro.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.parallel.collectives import hierarchical_grad_sync
 
@@ -146,7 +146,8 @@ def test_gspmd_train_step_on_2d_mesh():
         from repro.optim.schedule import constant
         from repro.training import TrainState, make_train_step
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.compat import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = get_config("granite-moe-1b-a400m", reduced=True)
         opt = adamw(constant(1e-3))
         params = init_params(cfg, jax.random.PRNGKey(0))

@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from repro.core import testfns
+from repro.kernels.chess_hvp import block_rows, resolve_interpret
 from repro.kernels.ops import (_fn_and_consts, chess_hvp, hdual_linear,
                                hdual_linear_apply)
 from repro.kernels.ref import chess_hvp_ref, hdual_linear_ref
@@ -230,3 +231,25 @@ def test_hdual_linear_second_derivative_through_network():
     np.testing.assert_allclose(np.asarray(out.dij),
                                np.asarray(H[2, :csize]), rtol=1e-3,
                                atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# shapes and modes the chip's compiler takes
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("m,blk_m,want", [
+    (500_000, 8, 8), (500_000, 4, 8), (500_000, 9, 16), (500_000, 16, 16),
+    (5, 8, 5), (3, 16, 3), (24, 1, 8),
+])
+def test_block_rows_heights_mosaic_takes(m, blk_m, want):
+    """A block is a multiple of the 8-row sublane tile or all of m."""
+    assert block_rows(m, blk_m) == want
+
+
+def test_interpret_default_off_tpu_and_refused_on_it(monkeypatch):
+    assert resolve_interpret(None) is True          # this CPU suite
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_interpret(None) is False
+    assert resolve_interpret(False) is False
+    with pytest.raises(ValueError, match="refused on the TPU"):
+        resolve_interpret(True)

@@ -348,3 +348,17 @@ def test_wire_slow_ring_and_events():
             assert got["traces"][0]["meta"]["client"] == "slowpoke"
             kinds = [e["kind"] for e in got["events"]]
             assert "retune" in kinds
+
+
+def test_profile_session_raises_when_the_trace_cannot_start(monkeypatch):
+    from jax import profiler as jp
+    from repro.obs import profile as prof
+
+    def broken(*a, **k):
+        raise RuntimeError("profiler plugin missing")
+
+    monkeypatch.setattr(jp, "start_trace", broken)
+    with pytest.raises(RuntimeError, match="plugin missing"):
+        with prof.profile_session("unused-dir"):
+            pass
+    assert not prof.is_active()         # a later session may start

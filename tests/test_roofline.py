@@ -11,6 +11,7 @@ the gate trusts.
 import pytest
 
 from repro.core.api import num_chunk_evals
+from repro.launch.hlo_analysis import peaks_for
 from repro.launch.roofline import (_executed_cells, _sweep_gate,
                                    render_curvature)
 
@@ -83,3 +84,17 @@ def test_render_curvature_table_md():
     txt = render_curvature(recs, md=True)
     assert txt.startswith("| backend")
     assert "speedup = 2.00x" in txt
+
+
+def test_peaks_table_is_keyed_by_device_kind():
+    p = peaks_for("TPU v5 lite")
+    assert (p.flops, p.hbm_bw) == (197e12, 819e9) and "v5e" in p.source
+    with pytest.raises(KeyError, match="no published peaks"):
+        peaks_for("cpu")
+
+
+def test_render_says_not_measured_off_the_chip():
+    rec = _rec("vmap_l2", "full", 16, 16, flops=1e6, bytes=1e5,
+               measured_s=2e-4, bound_s=None, pct_roofline=None,
+               status="measured")
+    assert "not measured" in render_curvature([rec])
