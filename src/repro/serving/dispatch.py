@@ -31,6 +31,7 @@ batches into device work:
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from typing import Optional
@@ -48,28 +49,35 @@ from .scheduler import PlanQueue, Scheduler
 __all__ = ["Dispatcher"]
 
 
-def _record_batch_spans(live, t0: float, t1: float, meta: dict) -> None:
+def _record_batch_spans(live, marks, meta: dict) -> None:
     """Attach the scheduling/execution spans to every traced request of a
     batch.  ``meta`` is ONE shared dict per batch (bucket id, pad stats,
     cross-n family) referenced by all member spans -- the flight recorder
     never mutates it.
 
-    Selection, coalescing and device execution are batch-level instants
-    (``take_ready_batch`` stamps one ``selected`` time on every member),
-    so those three spans are built ONCE as a shared tuple-of-tuples and
-    extended onto each member's span list; only the enqueue span differs
-    per request (its own submit time)."""
+    Coalescing (``take_ready_batch`` entry -> selection, stamped once per
+    batch on every member), the dispatch wait, marshalling, device
+    execution and readback are batch-level intervals, so those five spans
+    are built ONCE as a shared tuple-of-tuples and extended onto each
+    member's span list; only the enqueue span differs per request (its own
+    submit time).  ``marks`` are the bucket's stage boundaries from
+    ``Dispatcher._run``: marshal start, execute start, execute end (after
+    ``block_until_ready``) and readback end."""
+    t_m, t0, t1, t2 = marks
     shared = None
     for r in live:
         tr = r.trace
         if tr is None:
             continue
-        sel = tr.marks.get("selected", t0)
+        sel = tr.marks.get("selected", t_m)
+        c0 = tr.marks.get("coalesce", sel)
         if shared is None:
-            shared = (("coalesce", sel, sel, meta),
-                      ("dispatch_wait", sel, t0, None),
-                      ("device_execute", t0, t1, meta))
-        tr.add_span("enqueue", tr.marks.get("enqueued", tr.t_start), sel)
+            shared = (("coalesce", c0, sel, meta),
+                      ("dispatch_wait", sel, t_m, None),
+                      ("marshal", t_m, t0, None),
+                      ("device_execute", t0, t1, meta),
+                      ("readback", t1, t2, None))
+        tr.add_span("enqueue", tr.marks.get("enqueued", tr.t_start), c0)
         tr.spans.extend(shared)
 
 
@@ -77,6 +85,16 @@ def _fail_traces(live, exc: Exception) -> None:
     for r in live:
         if r.trace is not None:
             r.trace.finish(error=type(exc).__name__)
+
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _stage(active: bool, name: str, **meta):
+    """The ``repro:<stage>`` profiler annotation while a capture runs
+    (``active`` is read once per bucket), else a shared no-op context:
+    outside a capture no annotation object is constructed."""
+    return obs.annotate(name, **meta) if active else _NO_SPAN
 
 
 class Dispatcher:
@@ -155,7 +173,11 @@ class Dispatcher:
                     continue        # loop back to the drain branch
                 delay = sched.next_deadline_delay()
             # wait for a submit nudge or the oldest request's deadline
-            sched.wake.wait(delay)
+            if obs.is_active():
+                with obs.annotate("repro:worker_wait"):
+                    sched.wake.wait(delay)
+            else:
+                sched.wake.wait(delay)
 
     # -- execution ----------------------------------------------------------
 
@@ -182,49 +204,37 @@ class Dispatcher:
             tuned = q.exec_by_bucket.get(bucket)
         xplan, xbackend, xkey = tuned if tuned is not None \
             else (q.plan, q.backend, q.key)
-        try:
-            # marshal BOTH operands before t0: telemetry must charge the
-            # same work to hvp and hessian buckets (execution + readback,
-            # not host-to-device marshalling).  Pytree buckets were raveled
-            # per request at submit time, so this is still ONE device
-            # transfer per operand per bucket.
+
+        def marshal():
+            # BOTH operands are marshalled before the execute stage:
+            # telemetry must charge the same work to hvp and hessian
+            # buckets (execution + readback, not host-to-device
+            # marshalling).  Pytree buckets were raveled per request at
+            # submit time, so this is still ONE device transfer per
+            # operand per bucket.
             A = jnp.asarray(pad_rows(np.stack([r.a for r in live]), bucket))
-            V = None if q.workload == "batched_hessian" else jnp.asarray(
-                pad_rows(np.stack([r.v for r in live]), bucket))
-            t0 = time.perf_counter()
+            if q.workload == "batched_hessian":
+                return (A,)
+            V = jnp.asarray(pad_rows(np.stack([r.v for r in live]), bucket))
             if q.workload == "batched_diag":
                 # per-row probe budgets: padding rows inherit the last
                 # row's budget (their output is sliced off anyway)
-                P = jnp.asarray(pad_rows(
+                return A, V, jnp.asarray(pad_rows(
                     np.asarray([r.p for r in live], np.int32), bucket))
-                xargs = (A, V, P)
-            elif V is not None:        # pytree + flat hvp/diag alike
-                xargs = (A, V)
-            else:
-                xargs = (A,)
-            exe = xplan.executable(q.workload)
-            if obs.is_active():
-                # name device work in the profiler timeline; the is_active
-                # pre-check keeps the annotation object off the hot path
-                # outside capture sessions
-                with obs.annotate(
-                        f"repro:{q.workload}:{xbackend}:b{bucket}"):
-                    out = exe(*xargs)
-            else:
-                out = exe(*xargs)
-            out = np.asarray(jax.block_until_ready(out))
-            elapsed = time.perf_counter() - t0
-        except Exception as e:
-            for r in live:
-                r.future.set_exception(e)
-            _fail_traces(live, e)
+            return A, V                # pytree + flat hvp alike
+
+        meta = {"bucket": bucket, "rows": k, "backend": xbackend,
+                "workload": q.workload, "n_pad": int(live[0].a.shape[0])}
+        ran = self._run(live, xplan, q.workload, marshal, meta)
+        if ran is None:
             return
+        out, marks, active = ran
         # telemetry charges the executable that actually ran -- after a
         # hot-swap the winner's signature accumulates the fresh history the
         # drift detector compares against its tuned baseline
         registry.record_execution(xkey, xbackend, q.workload,
                                   bucket=bucket, n_points=k,
-                                  elapsed_s=elapsed,
+                                  elapsed_s=marks[3] - marks[1],
                                   clients=self._client_rows(live))
         with sched.lock:
             sched.stats["dispatched"] += k
@@ -235,30 +245,31 @@ class Dispatcher:
             q.epoch_points += k
         traced = obs.enabled()
         if traced:
-            meta = {"bucket": bucket, "rows": k,
-                    "padded_rows": bucket - k, "backend": xbackend,
-                    "workload": q.workload, "ragged": False}
-            _record_batch_spans(live, t0, t0 + elapsed, meta)
-        for i, r in enumerate(live):
-            tr = r.trace if traced else None
-            r0 = tr.clock() if tr is not None else 0.0
-            # copy: out[i] would be a view pinning the whole padded bucket
-            # (max_batch rows) for as long as the client keeps its result
-            row = out[i].copy()
-            if q.spec is not None:
-                try:
-                    row = q.spec.unravel(row)
-                except Exception as e:      # pragma: no cover - spec bug
-                    r.future.set_exception(e)
-                    if tr is not None:
-                        tr.finish(error=type(e).__name__)
-                    continue
-            r.future.set_result(row)
-            if tr is not None:
-                # "respond" covers unravel + future resolution, which runs
-                # the frontend's done-callback (socket write) synchronously
-                tr.add_span("respond", r0, tr.clock())
-                tr.finish()
+            _record_batch_spans(live, marks, {
+                **meta, "padded_rows": bucket - k, "ragged": False})
+        with _stage(active, "repro:respond"):
+            for i, r in enumerate(live):
+                tr = r.trace if traced else None
+                r0 = tr.clock() if tr is not None else 0.0
+                # copy: out[i] would be a view pinning the whole padded
+                # bucket (max_batch rows) for as long as the client keeps
+                # its result
+                row = out[i].copy()
+                if q.spec is not None:
+                    try:
+                        row = q.spec.unravel(row)
+                    except Exception as e:  # pragma: no cover - spec bug
+                        r.future.set_exception(e)
+                        if tr is not None:
+                            tr.finish(error=type(e).__name__)
+                        continue
+                r.future.set_result(row)
+                if tr is not None:
+                    # "respond" covers unravel + future resolution, which
+                    # runs the frontend's done-callback (socket write)
+                    # synchronously
+                    tr.add_span("respond", r0, tr.clock())
+                    tr.finish()
 
     def _execute_ragged(self, q: PlanQueue, live) -> None:
         """Run one mixed-n bucket through the family's ragged executable."""
@@ -268,32 +279,25 @@ class Dispatcher:
         n_pad = max(r.n for r in live)
         with sched.lock:
             gplan, gbackend, gkey = q.group.plan_for(n_pad)
-        try:
+
+        def marshal():
             A = jnp.asarray(pad_rows(np.stack(
                 [pad_cols(np.asarray(r.a), n_pad) for r in live]), bucket))
             V = jnp.asarray(pad_rows(np.stack(
                 [pad_cols(np.asarray(r.v), n_pad) for r in live]), bucket))
             NE = jnp.asarray(pad_rows(
                 np.asarray([r.n for r in live], np.int32), bucket))
-            t0 = time.perf_counter()
-            exe = gplan.executable("batched_hvp_ragged")
-            if obs.is_active():
-                with obs.annotate(
-                        f"repro:batched_hvp_ragged:{gbackend}"
-                        f":b{bucket}:n{n_pad}"):
-                    out = exe(A, V, NE)
-            else:
-                out = exe(A, V, NE)
-            out = np.asarray(jax.block_until_ready(out))
-            elapsed = time.perf_counter() - t0
-        except Exception as e:
-            for r in live:
-                r.future.set_exception(e)
-            _fail_traces(live, e)
+            return A, V, NE
+
+        meta = {"bucket": bucket, "rows": k, "backend": gbackend,
+                "workload": "batched_hvp_ragged", "n_pad": n_pad}
+        ran = self._run(live, gplan, "batched_hvp_ragged", marshal, meta)
+        if ran is None:
             return
+        out, marks, active = ran
         registry.record_execution(gkey, gbackend, "batched_hvp_ragged",
                                   bucket=bucket, n_points=k,
-                                  elapsed_s=elapsed,
+                                  elapsed_s=marks[3] - marks[1],
                                   clients=self._client_rows(live))
         with sched.lock:
             sched.stats["dispatched"] += k
@@ -308,20 +312,47 @@ class Dispatcher:
         traced = obs.enabled()
         if traced:
             ns = [r.n for r in live]
-            meta = {"bucket": bucket, "rows": k,
-                    "padded_rows": bucket - k, "backend": gbackend,
-                    "workload": "batched_hvp_ragged", "ragged": True,
-                    "family": q.group.family.name, "n_pad": n_pad,
-                    "pad_waste": round(
-                        1.0 - sum(ns) / float(len(ns) * n_pad), 4)}
-            _record_batch_spans(live, t0, t0 + elapsed, meta)
-        for i, r in enumerate(live):
-            tr = r.trace if traced else None
-            r0 = tr.clock() if tr is not None else 0.0
-            r.future.set_result(out[i, :r.n].copy())
-            if tr is not None:
-                tr.add_span("respond", r0, tr.clock())
-                tr.finish()
+            _record_batch_spans(live, marks, {
+                **meta, "padded_rows": bucket - k, "ragged": True,
+                "family": q.group.family.name,
+                "pad_waste": round(
+                    1.0 - sum(ns) / float(len(ns) * n_pad), 4)})
+        with _stage(active, "repro:respond"):
+            for i, r in enumerate(live):
+                tr = r.trace if traced else None
+                r0 = tr.clock() if tr is not None else 0.0
+                r.future.set_result(out[i, :r.n].copy())
+                if tr is not None:
+                    tr.add_span("respond", r0, tr.clock())
+                    tr.finish()
+
+    @staticmethod
+    def _run(live, plan, workload: str, marshal, meta: dict):
+        """Marshal, execute and read back one bucket, each stage under its
+        ``repro:`` annotation while a capture runs.  Returns ``(out, marks,
+        active)``: the host output, the stage boundaries (marshal start,
+        execute start, execute end after ``block_until_ready``, readback
+        end) and whether a capture was running; or None after failing
+        every future of the bucket."""
+        active = obs.is_active()
+        try:
+            t_m = time.perf_counter()
+            with _stage(active, "repro:marshal"):
+                xargs = marshal()
+                exe = plan.executable(workload)
+            t0 = time.perf_counter()
+            with _stage(active, "repro:device_execute", **meta):
+                out = jax.block_until_ready(exe(*xargs))
+            t1 = time.perf_counter()
+            with _stage(active, "repro:readback"):
+                out = np.asarray(out)
+            t2 = time.perf_counter()
+        except Exception as e:
+            for r in live:
+                r.future.set_exception(e)
+            _fail_traces(live, e)
+            return None
+        return out, (t_m, t0, t1, t2), active
 
     @staticmethod
     def _client_rows(live) -> Optional[dict]:

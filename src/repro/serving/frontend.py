@@ -48,6 +48,13 @@ from . import protocol
 __all__ = ["CurvatureFrontend", "CurvatureClient", "connect"]
 
 
+def _answer(reply: Callable, rid, fut: Future) -> None:
+    """Write a resolved request's result (or its error) to its caller."""
+    exc = fut.exception()
+    reply(protocol.error_frame(rid, exc) if exc is not None
+          else protocol.result_frame(rid, fut.result().tolist()))
+
+
 class CurvatureFrontend:
     """Threaded TCP server bridging the wire protocol onto a service.
 
@@ -204,13 +211,18 @@ class CurvatureFrontend:
             for line in reader:
                 if self._stopped.is_set():
                     break
-                rid = None
-                try:
-                    frame = protocol.decode(line)
-                    rid = frame.get("id")
-                    self._handle(frame, rid, reply)
-                except Exception as e:      # typed -> wire code
-                    reply(protocol.error_frame(rid, e))
+                # two host stages per frame, each under its repro:
+                # annotation while a profiler capture runs
+                if obs.is_active():
+                    with obs.annotate("repro:decode"):
+                        job = self._decode(line, reply)
+                    if job is not None:
+                        with obs.annotate("repro:submit"):
+                            self._submit(job, reply)
+                else:
+                    job = self._decode(line, reply)
+                    if job is not None:
+                        self._submit(job, reply)
         finally:
             try:
                 reader.close()
@@ -223,24 +235,86 @@ class CurvatureFrontend:
             with self._conn_lock:
                 self._conns.discard(conn)
 
-    def _handle(self, frame: dict, rid, reply: Callable) -> None:
-        method = frame.get("method")
+    def _decode(self, line: bytes, reply: Callable):
+        """Parse one frame.  Control methods are answered here; a compute
+        request comes back as ``(rid, plan, a, v, frame, trace)``
+        for ``_submit``.  Errors are answered here and return None."""
+        rid = None
+        trace = None
+        try:
+            frame = protocol.decode(line)
+            rid = frame.get("id")
+            method = frame.get("method")
+            if self._control(method, frame, rid, reply):
+                return None
+            # the trace starts HERE, at decode time, so queueing for
+            # admission and everything downstream -- including the
+            # response write, which runs inside the dispatch worker's
+            # done-callback -- lands on it
+            trace = obs.trace_begin(
+                rid=rid, method=method, client=frame.get("client"),
+                priority=frame.get("priority", DEFAULT_PRIORITY),
+                transport="tcp") if obs.enabled() else None
+            if "a" not in frame:
+                raise ValueError(f"{method} frame needs \"a\"")
+            plan = self._plan_for(frame.get("plan"), frame.get("n"))
+            a = np.asarray(frame["a"], np.float32)
+            v = None
+            if method == "hvp":
+                if "v" not in frame:
+                    raise ValueError("hvp frame needs \"v\"")
+                v = np.asarray(frame["v"], np.float32)
+            return rid, plan, a, v, frame, trace
+        except Exception as e:          # typed -> wire code
+            if trace is not None:
+                trace.finish(error=type(e).__name__)
+            reply(protocol.error_frame(rid, e))
+            return None
+
+    def _submit(self, job: tuple, reply: Callable) -> None:
+        """Hand a decoded request to the service; its reply is written
+        from the future's done-callback (a dispatch worker)."""
+        rid, plan, a, v, frame, trace = job
+        try:
+            fut = self.service.submit(
+                plan, a, v, client=frame.get("client"),
+                priority=frame.get("priority", DEFAULT_PRIORITY),
+                trace=trace)
+        except Exception as e:
+            # submit() seals the trace for its own rejections (finish is
+            # idempotent); this covers validation errors before them
+            if trace is not None:
+                trace.finish(error=type(e).__name__)
+            reply(protocol.error_frame(rid, e))
+            return
+
+        def _done(f: Future) -> None:
+            if obs.is_active():
+                with obs.annotate("repro:reply"):
+                    _answer(reply, rid, f)
+            else:
+                _answer(reply, rid, f)
+
+        fut.add_done_callback(_done)
+
+    def _control(self, method, frame: dict, rid, reply: Callable) -> bool:
+        """Answer a non-compute method; False for ``hvp`` / ``hessian``."""
         if method == "ping":
             reply(protocol.result_frame(rid, "pong"))
-            return
+            return True
         if method == "plans":
             listing = {
                 name: {"family": callable(spec)
                        and not hasattr(spec, "executable")}
                 for name, spec in self.plans.items()}
             reply(protocol.result_frame(rid, listing))
-            return
+            return True
         if method == "stats":
             stats = self.service.stats()
             stats["buckets"] = {str(k): v
                                 for k, v in stats["buckets"].items()}
             reply(protocol.result_frame(rid, stats))
-            return
+            return True
         if method == "metrics":
             fmt = frame.get("format", "json")
             reg = obs.metrics_registry()
@@ -252,7 +326,7 @@ class CurvatureFrontend:
                 raise ValueError(
                     f"metrics format must be 'json' or 'prometheus', "
                     f"got {fmt!r}")
-            return
+            return True
         if method == "trace":
             rec = obs.recorder()
             k = int(frame.get("k", 16))
@@ -262,47 +336,12 @@ class CurvatureFrontend:
                 "traces": [t.to_dict() for t in traces],
                 "events": rec.events(k),
             }))
-            return
+            return True
         if method not in ("hvp", "hessian"):
             raise ValueError(
                 f"unknown method {method!r}; expected one of "
                 f"{protocol.METHODS}")
-        # the trace starts HERE, at decode time, so queueing for admission
-        # and everything downstream -- including the response write, which
-        # runs inside the dispatch worker's done-callback -- lands on it
-        trace = obs.trace_begin(
-            rid=rid, method=method, client=frame.get("client"),
-            priority=frame.get("priority", DEFAULT_PRIORITY),
-            transport="tcp") if obs.enabled() else None
-        try:
-            if "a" not in frame:
-                raise ValueError(f"{method} frame needs \"a\"")
-            plan = self._plan_for(frame.get("plan"), frame.get("n"))
-            a = np.asarray(frame["a"], np.float32)
-            v = None
-            if method == "hvp":
-                if "v" not in frame:
-                    raise ValueError("hvp frame needs \"v\"")
-                v = np.asarray(frame["v"], np.float32)
-            priority = frame.get("priority", DEFAULT_PRIORITY)
-            fut = self.service.submit(
-                plan, a, v, client=frame.get("client"), priority=priority,
-                trace=trace)
-        except Exception as e:
-            # submit() seals the trace for its own rejections (finish is
-            # idempotent); this covers decode/marshal failures before it
-            if trace is not None:
-                trace.finish(error=type(e).__name__)
-            raise
-
-        def _done(f: Future, _rid=rid) -> None:
-            exc = f.exception()
-            if exc is not None:
-                reply(protocol.error_frame(_rid, exc))
-            else:
-                reply(protocol.result_frame(_rid, f.result().tolist()))
-
-        fut.add_done_callback(_done)
+        return False
 
 
 class CurvatureClient:
@@ -331,6 +370,14 @@ class CurvatureClient:
     # -- plumbing -----------------------------------------------------------
 
     def _call(self, method: str, **fields) -> Future:
+        """Send one request frame; its future resolves when the reader
+        thread sees the reply.  Array fields go on the wire as lists."""
+        if obs.is_active():
+            with obs.annotate("repro:client_send"):
+                return self._send(method, fields)
+        return self._send(method, fields)
+
+    def _send(self, method: str, fields: dict) -> Future:
         fut: Future = Future()
         with self._lock:
             if self._closed:
@@ -339,7 +386,8 @@ class CurvatureClient:
             self._next_id += 1
             self._futures[rid] = fut
         frame = {"id": rid, "method": method}
-        frame.update({k: v for k, v in fields.items() if v is not None})
+        frame.update({k: v.tolist() if isinstance(v, np.ndarray) else v
+                      for k, v in fields.items() if v is not None})
         try:
             with self._wlock:
                 self._sock.sendall(protocol.encode(frame))
@@ -349,12 +397,22 @@ class CurvatureClient:
             raise ServiceClosed(f"connection lost: {e}") from None
         return fut
 
+    def _match(self, line: bytes):
+        """Decode one reply frame and pop the future it answers."""
+        frame = protocol.decode(line)
+        with self._lock:
+            return frame, self._futures.pop(frame.get("id"), None)
+
     def _read_loop(self) -> None:
         try:
             for line in self._reader:
-                frame = protocol.decode(line)
-                with self._lock:
-                    fut = self._futures.pop(frame.get("id"), None)
+                # the repro:client_recv annotation ends before the future
+                # resolves, so the caller's callbacks fall outside it
+                if obs.is_active():
+                    with obs.annotate("repro:client_recv"):
+                        frame, fut = self._match(line)
+                else:
+                    frame, fut = self._match(line)
                 if fut is None:
                     continue        # response to a forgotten request
                 if frame.get("ok"):
@@ -385,7 +443,7 @@ class CurvatureClient:
         v = np.asarray(v, np.float32)
         return self._call(
             "hvp", plan=plan, n=int(n) if n is not None else len(a),
-            a=a.tolist(), v=v.tolist(),
+            a=a, v=v,
             client=client if client is not None else self.client,
             priority=priority)
 
@@ -395,7 +453,7 @@ class CurvatureClient:
         a = np.asarray(a, np.float32)
         return self._call(
             "hessian", plan=plan, n=int(n) if n is not None else len(a),
-            a=a.tolist(),
+            a=a,
             client=client if client is not None else self.client,
             priority=priority)
 

@@ -39,6 +39,7 @@ from __future__ import annotations
 
 import collections
 import threading
+import time
 from concurrent.futures import Future
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
@@ -444,7 +445,15 @@ class Scheduler:
         Returns (queue, requests) or None.  The requests may include
         cross-n fills pulled from the queue's RaggedGroup siblings (the
         dispatcher detects the mixed widths and routes the batch through
-        the family's ragged executable)."""
+        the family's ragged executable).
+
+        Coalescing is timed from this call's entry (one clock read per
+        call, so per batch, not per request) to the selection instant,
+        both stamped on every member's trace (``coalesce``, ``selected``).
+        While a profiler capture runs, choosing among the ready queues,
+        ``_select`` and ``_fill_cross_n`` run under a ``repro:coalesce``
+        annotation; a call that finds nothing ready records none."""
+        t_in = time.perf_counter()
         with self.space:
             ready = []
             for key, q in self.queues.items():
@@ -463,29 +472,11 @@ class Scheduler:
                 ready.append((key, q, eff_batch, full))
             if not ready:
                 return None
-            if len(ready) == 1 or all(e[1].tagged == 0 for e in ready):
-                key, q, eff_batch, full = ready[0]    # FIFO fast path
+            if obs.is_active():
+                with obs.annotate("repro:coalesce"):
+                    q, reqs = self._take(ready)
             else:
-                floor = min(e[1].queue_vt for e in ready)
-                key, q, eff_batch, full = min(
-                    ready, key=lambda e: e[1].queue_vt)
-                clients = {r.client for r in q.requests}
-                agg = sum(self.weight_of(c) for c in clients)
-                q.queue_vt = (max(q.queue_vt, floor)
-                              + 1.0 / max(agg, 1e-9))
-                if floor > 1e9:     # keep the clocks bounded
-                    for qq in self.queues.values():
-                        qq.queue_vt = max(qq.queue_vt - floor, 0.0)
-            k = min(len(q.requests), eff_batch)
-            reqs = self._select(q, k)
-            if (q.group is not None and len(reqs) < eff_batch
-                    and not full):
-                # only PARTIAL buckets are topped up: a full bucket has
-                # zero padding waste, merging can only dilute it
-                self._fill_cross_n(q, reqs, eff_batch)
-            self.pending -= len(reqs)
-            self.queues.move_to_end(key)
-            self.space.notify_all()
+                q, reqs = self._take(ready)
         # one clock read for the whole batch: selection is a batch-level
         # instant, and per-request clock calls are measurable at this rate
         t_sel = None
@@ -494,7 +485,36 @@ class Scheduler:
             if tr is not None:
                 if t_sel is None:
                     t_sel = tr.clock()
+                tr.marks["coalesce"] = t_in
                 tr.marks["selected"] = t_sel
+        return q, reqs
+
+    def _take(self, ready: list):
+        """Pick one of the ready queues and pop its batch (caller holds
+        the lock); see ``take_ready_batch``."""
+        if len(ready) == 1 or all(e[1].tagged == 0 for e in ready):
+            key, q, eff_batch, full = ready[0]    # FIFO fast path
+        else:
+            floor = min(e[1].queue_vt for e in ready)
+            key, q, eff_batch, full = min(
+                ready, key=lambda e: e[1].queue_vt)
+            clients = {r.client for r in q.requests}
+            agg = sum(self.weight_of(c) for c in clients)
+            q.queue_vt = (max(q.queue_vt, floor)
+                          + 1.0 / max(agg, 1e-9))
+            if floor > 1e9:     # keep the clocks bounded
+                for qq in self.queues.values():
+                    qq.queue_vt = max(qq.queue_vt - floor, 0.0)
+        k = min(len(q.requests), eff_batch)
+        reqs = self._select(q, k)
+        if (q.group is not None and len(reqs) < eff_batch
+                and not full):
+            # only PARTIAL buckets are topped up: a full bucket has
+            # zero padding waste, merging can only dilute it
+            self._fill_cross_n(q, reqs, eff_batch)
+        self.pending -= len(reqs)
+        self.queues.move_to_end(key)
+        self.space.notify_all()
         return q, reqs
 
     def _select(self, q: PlanQueue, k: int) -> list:

@@ -12,8 +12,11 @@ Three layers of witness:
     it that way;
   * **end-to-end** -- a TCP client drives a frontend with admission
     configured and reads back traces whose spans cover the whole path
-    (admit -> enqueue -> coalesce -> dispatch_wait -> device_execute ->
-    respond) plus metrics in both wire formats.
+    (admit -> enqueue -> coalesce -> dispatch_wait -> marshal ->
+    device_execute -> readback -> respond) plus metrics in both wire
+    formats;
+  * **profiler** -- any capture lights the ``repro:`` annotations, and
+    none is constructed outside one.
 """
 
 import json
@@ -313,12 +316,20 @@ def test_wire_traces_cover_the_full_request_path():
             assert traces, "no trace reached the flight recorder"
             tr = traces[0]
             names = [s["name"] for s in tr["spans"]]
-            for want in ("admit", "enqueue", "coalesce", "dispatch_wait",
-                         "device_execute", "respond"):
+            path = ("enqueue", "coalesce", "dispatch_wait", "marshal",
+                    "device_execute", "readback", "respond")
+            for want in ("admit",) + path:
                 assert want in names, f"span {want!r} missing: {names}"
-            coalesce = next(s for s in tr["spans"]
-                            if s["name"] == "coalesce")
+            span = {s["name"]: s for s in tr["spans"]}
+            coalesce = span["coalesce"]
             assert coalesce["meta"]["bucket"] >= 1
+            assert coalesce["dur_ms"] >= 0
+            # the stages follow one another: each ends where the next
+            # starts, up to respond (its own clock read per request)
+            for a, b in zip(path[:-2], path[1:-1]):
+                end = span[a]["start_ms"] + span[a]["dur_ms"]
+                assert end == pytest.approx(span[b]["start_ms"],
+                                            abs=1e-9), (a, b)
             assert tr["meta"]["client"] == "e2e"
             assert tr["duration_ms"] > 0
             # both metric exporters over the same wire
@@ -362,3 +373,42 @@ def test_profile_session_raises_when_the_trace_cannot_start(monkeypatch):
         with prof.profile_session("unused-dir"):
             pass
     assert not prof.is_active()         # a later session may start
+
+
+def test_is_active_follows_a_bare_start_trace(tmp_path):
+    from jax import profiler as jp
+    assert not obs.is_active()
+    jp.start_trace(str(tmp_path))
+    try:
+        assert obs.is_active()
+        with pytest.raises(RuntimeError, match="already running"):
+            with obs.profile_session(str(tmp_path / "second")):
+                pass
+    finally:
+        jp.stop_trace()
+    assert not obs.is_active()
+
+
+def test_annotations_are_built_only_under_a_capture(monkeypatch, tmp_path):
+    from jax import profiler as jp
+    from repro.serving.frontend import CurvatureFrontend, connect
+    built = []
+
+    class Counting(jp.TraceAnnotation):
+        def __init__(self, name, **meta):
+            built.append(name)
+            super().__init__(name, **meta)
+
+    monkeypatch.setattr(jp, "TraceAnnotation", Counting)
+    fam = testfns.ragged_family("rosenbrock")
+    plans = {"rosenbrock": lambda n: engine.plan(fam, n, symmetric=False)}
+    with CurvatureFrontend(plans, max_batch=4, max_wait_us=200.0) as fe:
+        with connect(*fe.address, client="quiet") as cli:
+            a, v = _xv(8, seed=5)
+            cli.hvp("rosenbrock", a, v)
+            cli.hvp("rosenbrock", a, v)
+            assert built == []
+            with obs.profile_session(str(tmp_path)):
+                cli.hvp("rosenbrock", a, v)
+    assert {"repro:decode", "repro:device_execute",
+            "repro:reply"} <= set(built)
