@@ -190,7 +190,7 @@ def run_pr6_symmetric_wallclock(quick):
         # interpret-mode pallas: small cell, parity-path wall clock off-TPU
         "pallas": [(8, 8, 4)] if quick else [(16, 12, 4)],
     }
-    blk_m = 8
+    blk_m = 128
     records = []
     for backend, shape_list in shapes.items():
         for m, n, csize in shape_list:
@@ -269,7 +269,7 @@ def run(quick=False):
     emit("kernel/chess_hvp/xla_L2_us_per_point", f"{t_xla / m * 1e6:.2f}",
          f"m={m},n={n}")
     p_pl = engine.plan(f, n, m=m, csize=csize, backend="pallas",
-                       symmetric=False, blk_m=8)
+                       symmetric=False, blk_m=128)
     t_pl = time_fn(p_pl.batched_hvp, A, V)
     emit("kernel/chess_hvp/pallas_interpret_us_per_point",
          f"{t_pl / m * 1e6:.2f}", "interpret=True (CPU correctness path)")

@@ -15,8 +15,10 @@ Paper algorithm -> this module:
                                kernels/chess_hvp (Pallas)
 
 The GPU thread grid becomes vmap axes (DESIGN.md §3): on TPU, "a thread per
-(instance,row,chunk)" is a batched program over those axes, and XLA/Mosaic
-vectorize the trailing chunk axis onto VPU lanes.
+(instance,row,chunk)" is a batched program over those axes.  These schedules
+carry the chunk as a trailing axis of each hDual component and let XLA lay
+it out; the Pallas kernel (kernels/chess_hvp) puts the instances on the VPU
+lanes of every component instead.
 
 All chunk enumerations are static (numpy at trace time), so jit caches one
 executable per (n, csize, symmetric) signature -- the analogue of the paper's

@@ -11,7 +11,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .hdual import HDual, _chunk, _val
+from .hdual import HDual, _val
 
 __all__ = [
     "sin", "cos", "tan", "exp", "log", "sqrt", "tanh", "sigmoid", "abs",
@@ -87,8 +87,8 @@ def abs(u):  # noqa: A001 - mirrors the paper's abs overload
     if isinstance(u, HDual):
         s = jnp.sign(u.val)
         # |u|' = sign(u) u' ; |u|'' = sign(u) u'' (a.e., matching the C++ lib)
-        return HDual(jnp.abs(u.val), s * u.di, _chunk(s) * u.dj,
-                     _chunk(s) * u.dij)
+        return u._new(jnp.abs(u.val), s * u.di, u.chunk(s) * u.dj,
+                      u.chunk(s) * u.dij)
     return jnp.abs(u)
 
 
@@ -155,14 +155,24 @@ def where(c, a, b):
     """Branch select on the primal condition (paper's comparison overloads)."""
     if not (isinstance(a, HDual) or isinstance(b, HDual)):
         return jnp.where(c, a, b)
-    cs = a.csize if isinstance(a, HDual) else b.csize
+    h = a if isinstance(a, HDual) else b
+    cs, lead = h.csize, h.lead
+    shape = jnp.broadcast_shapes(jnp.shape(_val(a)), jnp.shape(_val(b)))
     if not isinstance(a, HDual):
-        a = HDual.constant(jnp.broadcast_to(jnp.asarray(a), jnp.shape(_val(b))), cs)
+        a = HDual.constant(jnp.broadcast_to(jnp.asarray(a), shape), cs,
+                           lead=lead)
     if not isinstance(b, HDual):
-        b = HDual.constant(jnp.broadcast_to(jnp.asarray(b), jnp.shape(_val(a))), cs)
-    cc = _chunk(c) if jnp.ndim(c) else c
-    return HDual(jnp.where(c, a.val, b.val), jnp.where(c, a.di, b.di),
-                 jnp.where(cc, a.dj, b.dj), jnp.where(cc, a.dij, b.dij))
+        b = HDual.constant(jnp.broadcast_to(jnp.asarray(b), shape), cs,
+                           lead=lead)
+    if a.lead != b.lead:
+        raise ValueError("HDual operands have different chunk layouts")
+    if lead:    # operands of lower rank broadcast behind the chunk axis
+        r = max(jnp.ndim(c), a.dj.ndim - 1, b.dj.ndim - 1)
+        a, b = a._lift(r), b._lift(r)
+        h = a
+    cc = h.chunk(c) if jnp.ndim(c) else c
+    return h._new(jnp.where(c, a.val, b.val), jnp.where(c, a.di, b.di),
+                  jnp.where(cc, a.dj, b.dj), jnp.where(cc, a.dij, b.dij))
 
 
 def maximum(a, b):
@@ -180,22 +190,36 @@ def sum(u, axis=None):  # noqa: A001
 
 
 def matvec_const(A, u):
-    """y = A @ u for a *constant* matrix A (m,n) and HDual vector u (n,).
+    """y = A @ u for a *constant* matrix A (p,n) and HDual vector u (n,) or
+    (n, B).
 
     Linear maps act componentwise on all 2c+2 hDual slots -- this is the
     identity exploited by the fused hdual_linear kernel (DESIGN.md §3).
-    The chunk slots are contracted one lane at a time: inside the Pallas
-    kernel a tensordot over (n, blk_m, csize) needs a 3-D <-> 2-D reshape
-    that Mosaic cannot lower.  ``Precision.HIGHEST`` keeps float32
-    products on the TPU, whose default matmul rounds inputs to bfloat16.
+    Trailing layout: the chunk slots are contracted one chunk at a time
+    (``x[..., l]``).  Chunk-leading layout (inside the ``chess_hvp``
+    kernel, value shape (n, B) with the instances on the lanes): every
+    component is a clean 2-D (n, B) slab, contracted slab by slab.
+    ``Precision.HIGHEST`` keeps float32 products on the TPU, whose default
+    matmul rounds inputs to bfloat16.
     """
     if not isinstance(u, HDual):
         return _mv(A, u)
+    c = u.csize
+    if not u.lead:
+        def chunks(x):
+            return jnp.stack([_mv(A, x[..., l]) for l in range(c)], -1)
 
-    def chunks(x):
-        return jnp.stack([_mv(A, x[..., l]) for l in range(u.csize)], -1)
+        return HDual(_mv(A, u.val), _mv(A, u.di), chunks(u.dj),
+                     chunks(u.dij))
+    # an operand of lower rank (a broadcast constant term) is widened to S
+    shape = jnp.shape(u.val)
 
-    return HDual(_mv(A, u.val), _mv(A, u.di), chunks(u.dj), chunks(u.dij))
+    def slabs(x):
+        return jnp.stack([_mv(A, jnp.broadcast_to(x[l], shape))
+                          for l in range(c)])
+
+    return HDual(_mv(A, u.val), _mv(A, jnp.broadcast_to(u.di, shape)),
+                 slabs(u.dj), slabs(u.dij), lead=True)
 
 
 def _mv(A, x):

@@ -501,9 +501,12 @@ def _combo_grid(fp: str, n, mm: int, symmetric: bool, backend: str,
     if pinned_blk_m is not None:
         blk_ms = [int(pinned_blk_m)]
     else:
-        # block heights Mosaic takes (chess_hvp.block_rows): multiples of
-        # the sublane tile, or the whole probe batch when it is smaller
-        blk_ms = [b for b in (8, 16) if b <= mm] or [mm]
+        # instance-block widths Mosaic takes (chess_hvp.block_rows):
+        # multiples of the 128-lane tile narrower than the probe batch,
+        # and None -- the default, one 128-lane tile or all of a batch it
+        # covers, so 128 itself is never timed twice -- and no width is
+        # pinned that the probe batch cannot tell apart
+        blk_ms = [b for b in (256, 512, 1024) if b < mm] + [None]
     combos = []
     for bk in backends:
         for c in (csizes if _csize_swept(bk, workload) else [argmin]):

@@ -8,14 +8,27 @@ Here (TPU):    grid = (instance-blocks, cells) where the trailing grid
                schedule KEEPS -- ``core.api.chunk_pairs`` flattened, the
                same static enumeration the vmap schedules trace.  Each grid
                cell holds an hDual VECTOR of the whole n-variable input in
-               VMEM with a trailing csize chunk axis (lane-vectorized on
-               the VPU) and a block of instances on the sublane axis.  The
-               output block is the FULL padded row vector (blk_m, n_pad)
-               whose index map ignores the cell grid dim, so Mosaic keeps
-               it resident in VMEM across the whole cell sweep -- the
-               paper's shared-memory reduction becomes a VMEM accumulator,
-               and the symmetric schedule's mirrored contributions scatter
-               into the same resident block.
+               VMEM for a block of instances, and the instances ride the
+               128-lane axis in EVERY component: ``val`` and
+               ``di`` are (n, width) tiles, ``dj`` and ``dij`` chunk-leading
+               (csize, n, width) stacks of such tiles (``HDual(lead=True)``),
+               so every overloaded op is a full-lane VPU op and a constant
+               mat-vec is 2c+2 (n, width) MXU matmuls.  The wrapper
+               hands the kernel variables-major (n, m) operands and
+               transposes the result back.  The output block is the FULL
+               padded column block (n_pad, width) whose index map ignores
+               the cell grid dim, so Mosaic keeps it resident in VMEM
+               across the whole cell sweep -- the paper's shared-memory
+               reduction becomes a VMEM accumulator, and the symmetric
+               schedule's mirrored contributions scatter into the same
+               resident block.
+
+Instances, not chunk columns, fill the lanes: with the chunk on the lanes
+a component would fill csize of 128, and a block of sublane-high instance
+tiles would cost a grid step per 8 instances.  The block width is a
+multiple of 128 or all of m (``block_rows``), one lane tile unless
+``blk_m`` asks for more; a small batch (the server's buckets) is one block
+of all of m.
 
 Kernel v3 (PR 6) makes the symmetric schedule TRULY skip: v2 launched the
 full (rows x chunks) L2 grid and predicated below-diagonal cells with
@@ -37,14 +50,14 @@ witness tests and the roofline report assert against.
 v2's lifted preconditions are kept verbatim:
 
   ragged tails    : the chunk grid is ceil(n / csize); seed columns past n
-                    never match the one-hot iota so their dij lanes are
+                    never match the one-hot seed so their dij slabs are
                     zero, and every in-kernel contribution is masked on
                     ``col < n``.  Any ``csize >= 1`` is served.
-  m % blk_m       : the wrapper pads the instance axis by edge replication
+  m % width       : the wrapper pads the instance axis by edge replication
                     (padding rows stay inside f's domain; see
                     engine.pad_rows for the same rationale) and slices the
                     padding back off.  Any ``m >= 1`` is served; the block
-                    height is a multiple of 8 or all of ``m``
+                    width is a multiple of 128 or all of ``m``
                     (``block_rows``), the shapes Mosaic accepts.
 
 The symmetric masks are CHUNK-granular, matching ``core.api.hvp_impl``
@@ -53,9 +66,10 @@ strictly right of the diagonal block mirrors wholesale (H[i,j]*v[i] ->
 r[j]); the diagonal-block cell contributes directly for every column,
 including the below-diagonal columns inside it.
 
-VMEM footprint per grid cell = n * blk_m * (2*csize + 2) * 4B -- the paper's
-csize <-> fast-memory dial, verbatim, with VMEM playing the register/L1
-role (DESIGN.md §3) -- plus the (blk_m, n_pad) resident output row block.
+VMEM footprint of the hDual input per grid cell = n * width * (2*csize+2)
+* 4B -- the paper's csize <-> fast-memory dial, verbatim, with VMEM playing
+the register/L1 role (DESIGN.md §3) -- plus f's intermediates and the
+(n_pad, width) resident output block.
 
 The kernel is generic over any ``f`` written against repro.core.hmath /
 HDual ops (trace-time polymorphism = the paper's template instantiation);
@@ -90,25 +104,25 @@ def kernel_grid(m: int, n: int, csize: int, blk_m: int,
     and the roofline report assert against ``core.api.num_chunk_evals``.
     """
     from repro.core.api import num_chunk_evals
-    blk_m = block_rows(m, blk_m)
-    m_pad = -(-m // blk_m) * blk_m
-    return (m_pad // blk_m, num_chunk_evals(n, csize, symmetric))
+    return (-(-m // block_rows(m, blk_m)), num_chunk_evals(n, csize, symmetric))
 
 
-SUBLANES = 8      # f32 sublane tile: Mosaic's unit for a block's row count
+LANES = 128        # f32 lane tile: Mosaic's unit for a block's width
 
 
 def block_rows(m: int, blk_m: int) -> int:
-    """The instance-block height the kernel launches with.
+    """The instance-block width the kernel launches with.
 
-    Mosaic takes a block whose row count is a multiple of the sublane tile
-    or the whole (padded) instance axis, so a ``blk_m`` of at least ``m``
-    becomes ``m`` (one block) and any other value is rounded up to a
-    multiple of 8.
+    Instances ride the 128-lane axis, and Mosaic takes a block whose lane
+    extent is a multiple of 128 or the whole (padded) instance axis, so a
+    ``blk_m`` of at least ``m`` becomes ``m`` (one block) and any other
+    value is rounded up to a multiple of 128 (and to ``m`` when that
+    covers it).
     """
     if blk_m < 1:
         raise ValueError(f"blk_m={blk_m} must be >= 1")
-    return min(m, -(-blk_m // SUBLANES) * SUBLANES)
+    width = -(-blk_m // LANES) * LANES
+    return m if width >= m else width
 
 
 def resolve_interpret(interpret: bool | None) -> bool:
@@ -123,8 +137,8 @@ def resolve_interpret(interpret: bool | None) -> bool:
     return bool(interpret)
 
 
-def _kernel(rows_ref, starts_ref, a_ref, v_ref, *rest, f, n, n_pad, csize,
-            blk_m, symmetric, out_dtype):
+def _kernel(rows_ref, starts_ref, a_ref, v_ref, k_ref, *rest, f, n, n_pad,
+            csize, width, symmetric, out_dtype):
     consts = rest[:-1]
     out_ref = rest[-1]
     t = pl.program_id(1)                       # flattened (row, chunk) cell
@@ -132,41 +146,42 @@ def _kernel(rows_ref, starts_ref, a_ref, v_ref, *rest, f, n, n_pad, csize,
     cstart = starts_ref[t]                     # first column of the chunk
     first = t == 0
 
-    a = a_ref[...].astype(jnp.float32)         # (blk_m, n)
-    at = a.T                                   # (n, blk_m) variables-major
+    at = a_ref[...].astype(jnp.float32)        # (n, width) variables-major
 
-    k2 = jax.lax.broadcasted_iota(jnp.int32, (n, blk_m), 0)
+    # variable index of each row, loaded from a resident block: seeds built
+    # from an in-kernel iota get a lane-replicated layout, and Mosaic aborts
+    # when f then multiplies sublane-offset slices (x[1:] * x[:-1]) of them
+    k2 = k_ref[...]                            # (n, width) int32
     di = (k2 == i).astype(jnp.float32)
-    l2 = jax.lax.broadcasted_iota(jnp.int32, (blk_m, csize), 1)
-    # ragged tail: columns cstart+l >= n match no variable -> zero dj
-    # lanes -> zero dij lanes; the masks below drop them explicitly.  Built
-    # from 2-D iotas: Mosaic aborts on leading-axis slices (f's x[1:]) of
-    # a 3-D iota once blk_m spans more than one sublane tile.
-    dj = (k2[:, :, None] == cstart + l2[None, :, :]).astype(jnp.float32)
-    dij = jnp.zeros((n, blk_m, csize), jnp.float32)
+    # chunk-leading seed: one (n, width) slab per chunk column.  A ragged
+    # tail's columns cstart+l >= n match no variable -> zero dj slabs ->
+    # zero dij slabs; the masks below drop them explicitly.
+    dj = jnp.stack([(k2 == cstart + l).astype(jnp.float32)
+                    for l in range(csize)])
+    dij = jnp.zeros((csize, n, width), jnp.float32)
 
-    y = HDual(at, di, dj, dij)
-    r = f(y, *[cr[...] for cr in consts])      # HDual: dij (blk_m, csize)
+    y = HDual(at, di, dj, dij, lead=True)
+    r = f(y, *[cr[...] for cr in consts])      # HDual: dij (csize, width)
 
-    v = v_ref[...].astype(jnp.float32)         # (blk_m, n_pad), zero-padded
-    cols = cstart + jax.lax.broadcasted_iota(jnp.int32, (blk_m, csize), 1)
-    valid = cols < n
-    # chunk -> padded-column one-hot: the lane gathers v[:, cstart:+csize]
-    # and v[:, i] are exact one-hot where/sum reductions, because Mosaic
-    # has no lowering for a dynamic lane gather (take_along_axis)
-    lj = jax.lax.broadcasted_iota(jnp.int32, (csize, n_pad), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (csize, n_pad), 1)
-    sel = jj == cstart + lj                              # (csize, n_pad)
-    vc = jnp.sum(jnp.where(sel[None, :, :], v[:, None, :], 0.0), axis=2)
+    v = v_ref[...].astype(jnp.float32)         # (n_pad, width), zero-padded
+    lc = jax.lax.broadcasted_iota(jnp.int32, (csize, width), 0)
+    valid = cstart + lc < n
+    # chunk -> padded-row one-hot: v[cstart:+csize] and v[i] are exact
+    # one-hot where/sum reductions over the sublane axis (no dynamic
+    # slice of the block)
+    lj = jax.lax.broadcasted_iota(jnp.int32, (csize, n_pad, width), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (csize, n_pad, width), 1)
+    sel = jj == cstart + lj                              # (csize, n_pad, w)
+    vc = jnp.sum(jnp.where(sel, v[None], 0.0), axis=1)   # (csize, width)
     # direct: H[i, j] * v[j] -> r[i] for every valid column of the cell --
     # the compacted symmetric enumeration only ever reaches this kernel
     # with at-or-right-of-diagonal cells, and the diagonal-block cell
     # contributes ALL its columns directly (vmap_l2 semantics).
-    contrib = jnp.sum(jnp.where(valid, r.dij * vc, 0.0), axis=1,
-                      keepdims=True)                     # (blk_m, 1)
+    contrib = jnp.sum(jnp.where(valid, r.dij * vc, 0.0), axis=0,
+                      keepdims=True)                     # (1, width)
 
-    rowsel = jax.lax.broadcasted_iota(jnp.int32, (blk_m, n_pad), 1) == i
-    add = jnp.where(rowsel, contrib, 0.0)               # (blk_m, n_pad)
+    rowsel = jax.lax.broadcasted_iota(jnp.int32, (n_pad, width), 0) == i
+    add = jnp.where(rowsel, contrib, 0.0)                # (n_pad, width)
 
     if symmetric:
         # mirror: a cell strictly right of the diagonal block contributes
@@ -176,11 +191,10 @@ def _kernel(rows_ref, starts_ref, a_ref, v_ref, *rest, f, n, n_pad, csize,
         # chunk->row one-hot so the write stays a dense VPU op on the
         # resident output block.
         mirrors = cstart > (i // csize) * csize          # scalar, traced
-        vi = jnp.sum(jnp.where(rowsel, v, 0.0), axis=1,
-                     keepdims=True)                      # v[:, i]
+        vi = jnp.sum(jnp.where(rowsel, v, 0.0), axis=0,
+                     keepdims=True)                      # v[i], (1, width)
         mvals = jnp.where(valid & mirrors, r.dij, 0.0) * vi
-        add = add + jnp.sum(
-            jnp.where(sel[None, :, :], mvals[:, :, None], 0.0), axis=1)
+        add = add + jnp.sum(jnp.where(sel, mvals[:, None, :], 0.0), axis=0)
 
     @pl.when(first)
     def _init():
@@ -192,22 +206,20 @@ def _kernel(rows_ref, starts_ref, a_ref, v_ref, *rest, f, n, n_pad, csize,
 
 
 def chess_hvp_pallas(f: Callable, A, V, csize: int, *,
-                     consts: Sequence = (), blk_m: int = 8,
+                     consts: Sequence = (), blk_m: int = LANES,
                      symmetric: bool = False,
                      interpret: bool | None = None):
     """Batched HVP out[m] = H_f(A[m]) @ V[m] via the L2 grid schedule.
 
     A, V: (m, n). Returns (m, n).  Serves ANY (m, n, csize) with m >= 1 and
     csize >= 1: ragged tails (csize does not divide n) are masked in-kernel
-    and the instance axis is padded up to a blk_m multiple by edge
-    replication (v2; the seed kernel required csize | n and m % blk_m == 0).
-    ``symmetric=True`` launches the COMPACTED Alg. 8 grid: only
-    at-or-right-of-diagonal cells exist in the trip count (v3 -- no
-    predicated ghosts), and strictly-right cells are mirrored through the
-    VMEM output accumulator.  ``kernel_grid(m, n, csize, blk_m, symmetric)``
-    is the exact launch shape; ``block_rows`` says which block height a
-    requested ``blk_m`` becomes.  ``interpret`` follows
-    ``resolve_interpret``.
+    and the instance axis is padded up to a block multiple by edge
+    replication.  ``symmetric=True`` launches the COMPACTED Alg. 8 grid:
+    only at-or-right-of-diagonal cells exist in the trip count, and
+    strictly-right cells are mirrored through the VMEM output accumulator.
+    ``blk_m`` is the instance-block width (``block_rows``; one lane tile
+    by default); ``kernel_grid`` is the exact launch shape.  ``interpret``
+    follows ``resolve_interpret``.
     """
     from repro.core.api import chunk_pairs
 
@@ -215,10 +227,10 @@ def chess_hvp_pallas(f: Callable, A, V, csize: int, *,
     assert V.shape == (m, n)
     assert m >= 1 and csize >= 1, (m, csize)
     interpret = resolve_interpret(interpret)
-    blk_m = block_rows(m, blk_m)
+    width = block_rows(m, blk_m)
     nchunk = -(-n // csize)                    # ceil-div chunk grid
     n_pad = nchunk * csize
-    m_pad = -(-m // blk_m) * blk_m
+    m_pad = -(-m // width) * width
     if m_pad != m:
         # edge replication keeps padded instances inside f's domain (e.g.
         # Ackley's sqrt is non-differentiable at the zero vector)
@@ -238,22 +250,26 @@ def chess_hvp_pallas(f: Callable, A, V, csize: int, *,
     pairs = chunk_pairs(n, csize, symmetric)             # (P, 2) numpy
     rows_idx = jnp.asarray(pairs[:, 0])
     starts_idx = jnp.asarray(pairs[:, 1])
-    grid = (m_pad // blk_m, len(pairs))
-    assert grid == kernel_grid(m, n, csize, blk_m, symmetric)
+    grid = (m_pad // width, len(pairs))
+    assert grid == kernel_grid(m, n, csize, width, symmetric)
+    _record_launch(m, n, csize, symmetric, width, grid)
 
-    # index maps receive (mi, t, rows_ref, starts_ref): scalar-prefetch
-    # operands are appended by PrefetchScalarGridSpec
+    # instances on the lanes: the wrapper hands the kernel variables-major
+    # (n, m_pad) blocks and transposes the result back.  Index maps receive
+    # (mi, t, rows_ref, starts_ref): scalar-prefetch operands are appended
+    # by PrefetchScalarGridSpec
     in_specs = [
-        pl.BlockSpec((blk_m, n), lambda mi, t, rs, ss: (mi, 0)),      # A
-        pl.BlockSpec((blk_m, n_pad), lambda mi, t, rs, ss: (mi, 0)),  # V
+        pl.BlockSpec((n, width), lambda mi, t, rs, ss: (0, mi)),      # A^T
+        pl.BlockSpec((n_pad, width), lambda mi, t, rs, ss: (0, mi)),  # V^T
+        pl.BlockSpec((n, width), lambda mi, t, rs, ss: (0, 0)),       # k
     ]
     for cst in consts:
         in_specs.append(
             pl.BlockSpec(cst.shape,
                          lambda mi, t, rs, ss, _nd=cst.ndim: (0,) * _nd))
-    # full-row output block, resident across the cell sweep: both the
+    # whole-column output block, resident across the cell sweep: both the
     # per-row dot product and the symmetric mirror accumulate into it
-    out_spec = pl.BlockSpec((blk_m, n_pad), lambda mi, t, rs, ss: (mi, 0))
+    out_spec = pl.BlockSpec((n_pad, width), lambda mi, t, rs, ss: (0, mi))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -262,12 +278,34 @@ def chess_hvp_pallas(f: Callable, A, V, csize: int, *,
         out_specs=out_spec,
     )
     kernel = functools.partial(_kernel, f=f, n=n, n_pad=n_pad, csize=csize,
-                               blk_m=blk_m, symmetric=bool(symmetric),
+                               width=width, symmetric=bool(symmetric),
                                out_dtype=A.dtype)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((m_pad, n_pad), A.dtype),
+        out_shape=jax.ShapeDtypeStruct((n_pad, m_pad), A.dtype),
         interpret=interpret,
-    )(rows_idx, starts_idx, A, V, *consts)
-    return out[:m, :n]
+    )(rows_idx, starts_idx, A.T, V.T, jnp.broadcast_to(
+        jnp.arange(n, dtype=jnp.int32)[:, None], (n, width)), *consts)
+    return out[:n, :m].T
+
+
+def _record_launch(m, n, csize, symmetric, width, grid):
+    """Launch-shape record in the metrics registry, written when the
+    kernel is traced (once per compiled executable, never per call)."""
+    from repro import obs
+    reg = obs.default_registry()
+    shape = f"m{m}_n{n}_c{csize}_{'sym' if symmetric else 'full'}"
+    lanes = -(-width // LANES) * LANES
+    for name, help_, value in (
+            ("repro_chess_hvp_grid_steps", "Grid steps per kernel call.",
+             grid[0] * grid[1]),
+            ("repro_chess_hvp_block_width", "Instances per kernel block.",
+             width),
+            ("repro_chess_hvp_lanes_filled_pct",
+             "Real instances over the lane slots the blocks occupy.",
+             100.0 * m / (grid[0] * lanes))):
+        reg.gauge(name, help_, labelnames=("shape",)).set(value, shape=shape)
+    reg.counter("repro_chess_hvp_traces_total",
+                "chess_hvp kernels traced (one per compiled executable).",
+                labelnames=("shape",)).inc(shape=shape)

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 
 from repro.core import testfns
 from repro.engine.registry import BackendSpec, register_backend
-from repro.kernels.chess_hvp import chess_hvp_pallas, resolve_interpret
+from repro.kernels.chess_hvp import LANES, chess_hvp_pallas, resolve_interpret
 from repro.kernels.hdual_linear import hdual_linear_pallas
 
 __all__ = ["chess_hvp", "hdual_linear", "hdual_linear_apply", "kernel_form"]
@@ -57,9 +57,9 @@ def _pallas_make(plan, workload):
     # fails before anything is traced
     interpret = resolve_interpret(plan.opt("interpret"))
     # the wrapper pads m up to a block multiple, so blk_m is purely a
-    # tuning dial (the joint autotuner sweeps it); default to the sublane
-    # tile, which block_rows caps at m so tiny batches don't pad 8x
-    blk_m = plan.opt("blk_m") or 8
+    # tuning dial (the joint autotuner sweeps it); default to one lane
+    # tile, which block_rows caps at m so small batches are one block
+    blk_m = plan.opt("blk_m") or LANES
 
     def run(A, V):
         return chess_hvp_pallas(kernel_f, A, V, plan.csize, consts=consts,
@@ -82,7 +82,7 @@ register_backend(BackendSpec(
 @partial(jax.jit, static_argnames=("function", "csize", "blk_m", "symmetric",
                                    "interpret"))
 def chess_hvp(A, V, *, function: str = "rosenbrock", csize: int = 4,
-              blk_m: int = 8, symmetric: bool = False,
+              blk_m: int = LANES, symmetric: bool = False,
               interpret: bool | None = None):
     """Batched HVP on one of the paper's test-function families.
 

@@ -163,7 +163,7 @@ def curvature_records(quick: bool = False) -> list[dict]:
     # a roofline share is a device metric: only a TPU's own peaks apply,
     # and only to a time measured on it
     peaks = peaks_for(dev.device_kind) if dev.platform == "tpu" else None
-    blk_m = 8
+    blk_m = 128
     # pallas runs in interpret mode on CPU runners: keep its cell small
     configs = ([("vmap_l2", 16, 24, 4), ("pallas", 8, 8, 4)] if quick else
                [("vmap_l2", 32, 48, 4), ("pallas", 16, 12, 4)])

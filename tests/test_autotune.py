@@ -207,17 +207,31 @@ def test_candidates_include_ragged_csizes():
     assert max(engine.csize_candidates(1000)) == engine.LANE_WIDTH
 
 
-def test_pallas_blk_m_threads_into_plan():
-    """An explicit-backend pallas tune sweeps blk_m and the winning block
-    size lands in the plan's options."""
+def test_pallas_blk_m_threads_into_plan(monkeypatch):
+    """An explicit-backend pallas tune sweeps the lane-wide block widths
+    narrower than its probe batch against the kernel's derived width
+    (None), and a winning width lands in the plan's options."""
+    opts = (("interpret", True),)
+    # a probe batch of 8: no swept width is narrower, so only the
+    # derived width (one block of the batch) runs and nothing is pinned
     cfg = engine.autotune(testfns.rosenbrock, 4, m=8, reps=1,
-                          symmetric=False, backend="pallas",
-                          options=(("interpret", True),))
-    assert cfg.backend == "pallas" and cfg.blk_m in (4, 8)
+                          symmetric=False, backend="pallas", options=opts)
+    assert cfg.backend == "pallas" and cfg.blk_m is None
     p = engine.plan(testfns.rosenbrock, 4, m=8, csize="autotune",
                     backend="pallas", symmetric=False, interpret=True)
     assert p.csize == cfg.csize
-    assert p.opt("blk_m") == cfg.blk_m
+    assert p.opt("blk_m") is None
+    # a probe batch of 300: 256 lanes against the derived width (128)
+    cfg = engine.autotune(testfns.rosenbrock, 4, m=300, probe_m=75, reps=1,
+                          symmetric=False, backend="pallas", options=opts)
+    assert cfg.backend == "pallas" and cfg.blk_m in (256, None)
+    # a tuned width is threaded into the plan
+    won = at.TunedConfig(csize=2, backend="pallas", blk_m=256, time_s=0.0,
+                         source="sweep")
+    monkeypatch.setattr(at, "autotune", lambda *a, **k: won)
+    p = engine.plan(testfns.rosenbrock, 4, m=4096, csize="autotune",
+                    backend="pallas", symmetric=False, interpret=True)
+    assert p.csize == 2 and p.opt("blk_m") == 256
 
 
 # ---------------------------------------------------------------------------

@@ -112,7 +112,7 @@ def test_sweep_count_witness(n, csize):
 @pytest.mark.parametrize("function",
                          ["rosenbrock", "ackley", "fletcher_powell"])
 @pytest.mark.parametrize("n", [8, 10])
-@pytest.mark.parametrize("m,blk_m", [(1, 8), (12, 4)])
+@pytest.mark.parametrize("m,blk_m", [(1, 128), (140, 128)])
 def test_compacted_sym_parity_vs_oracle(function, n, m, blk_m):
     """Compacted-grid symmetric parity against the fwd-fwd oracle on all
     testfns x {divisible, ragged n} x {m=1, m > blk_m} (PR 6 satellite)."""
@@ -145,17 +145,18 @@ def test_symmetric_vs_full_schedules_agree():
 
 def test_instance_padding_is_invisible():
     """Padding rows (edge-replicated to stay in f's domain) must not leak
-    into real outputs: m=9 with blk_m=8 equals the same rows computed
-    unpadded."""
+    into real outputs: m=129 with 128-wide blocks equals the same rows
+    computed unpadded."""
     n, csize = 8, 4
     rng = np.random.RandomState(23)
-    A = jnp.asarray(rng.uniform(-2, 2, (9, n)), jnp.float32)
-    V = jnp.asarray(rng.randn(9, n), jnp.float32)
-    padded = chess_hvp(A, V, function="ackley", csize=csize, blk_m=8)
-    exact = chess_hvp(A[:8], V[:8], function="ackley", csize=csize, blk_m=8)
-    np.testing.assert_allclose(np.asarray(padded[:8]), np.asarray(exact),
+    A = jnp.asarray(rng.uniform(-2, 2, (129, n)), jnp.float32)
+    V = jnp.asarray(rng.randn(129, n), jnp.float32)
+    padded = chess_hvp(A, V, function="ackley", csize=csize, blk_m=128)
+    exact = chess_hvp(A[:128], V[:128], function="ackley", csize=csize,
+                      blk_m=128)
+    np.testing.assert_allclose(np.asarray(padded[:128]), np.asarray(exact),
                                rtol=1e-6, atol=1e-6)
-    assert padded.shape == (9, n)
+    assert padded.shape == (129, n)
 
 
 def test_chess_hvp_matches_jax_hessian():
@@ -234,15 +235,110 @@ def test_hdual_linear_second_derivative_through_network():
 
 
 # ---------------------------------------------------------------------------
+# kernel v4: instances on the lanes, lane-wide instance blocks
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("function,m,n,csize,blk_m,symmetric", [
+    ("rosenbrock", 300, 16, 2, 128, True),       # 3 lane blocks, m ragged
+    ("fletcher_powell", 300, 16, 2, 128, False),
+    ("ackley", 300, 10, 4, 256, True),           # ragged n, 2 lane blocks
+    ("fletcher_powell", 130, 9, 4, 128, True),   # ragged n and m
+    ("rosenbrock", 40, 7, 3, 128, False),        # m < one lane tile
+])
+def test_lane_blocks_match_vmap_l2_and_oracle(function, m, n, csize, blk_m,
+                                              symmetric):
+    """Several 128-lane instance blocks, a ragged last block and ragged
+    chunks agree with the vmap_l2 schedule and the fwd-fwd oracle."""
+    from repro import engine
+    from repro.kernels.chess_hvp import kernel_grid
+    rng = np.random.RandomState(m + n)
+    A = jnp.asarray(rng.uniform(-2, 2, (m, n)), jnp.float32)
+    V = jnp.asarray(rng.randn(m, n), jnp.float32)
+    out = np.asarray(chess_hvp(A, V, function=function, csize=csize,
+                               blk_m=blk_m, symmetric=symmetric))
+    f = testfns.FUNCTIONS[function](n)
+    l2 = engine.plan(f, n, m=m, csize=csize, backend="vmap_l2",
+                     symmetric=symmetric)
+    want = np.asarray(l2.batched_hvp(A, V))
+    np.testing.assert_allclose(out, want, rtol=1e-5,
+                               atol=1e-5 * (1 + np.abs(want).max()))
+    kf, consts = _fn_and_consts(function, n)
+    oracle = np.asarray(chess_hvp_ref(kf, A, V, csize, consts))
+    np.testing.assert_allclose(out, oracle, rtol=5e-3,
+                               atol=5e-3 * (1 + np.abs(oracle).max()))
+    blocks = kernel_grid(m, n, csize, blk_m, symmetric)[0]
+    assert blocks == -(-m // block_rows(m, blk_m)) == -(-m // blk_m)
+
+
+def _mixed_rank(x):
+    """Mixes ranks the way user code does: a row, a reduction and a
+    branch of lower rank meet the whole vector."""
+    import repro.core.hmath as hm
+    s = x.sum(0)
+    y = x * x[0] + hm.where(x[0] > 0.0, x, s) * s
+    return (y * y).sum(0)
+
+
+@pytest.mark.parametrize("n,csize,symmetric", [
+    (4, 4, True), (4, 4, False),     # csize == n: c would line up with n
+    (6, 4, True),                    # ragged chunks
+])
+def test_mixed_rank_f_matches_vmap_l2_and_jax_hessian(n, csize, symmetric):
+    """An f whose operands differ in rank gives the same HVPs on the
+    kernel's chunk-leading layout as on the vmap schedules."""
+    from repro import engine
+    m = 130
+    rng = np.random.RandomState(n + csize)
+    A = jnp.asarray(rng.uniform(-2, 2, (m, n)), jnp.float32)
+    V = jnp.asarray(rng.randn(m, n), jnp.float32)
+    got = {}
+    for backend in ("pallas", "vmap_l2"):
+        p = engine.plan(_mixed_rank, n, m=m, csize=csize, backend=backend,
+                        symmetric=symmetric, blk_m=128)
+        got[backend] = np.asarray(p.batched_hvp(A, V))
+    want = np.asarray(jax.vmap(
+        lambda a, v: jax.hessian(_mixed_rank)(a) @ v)(A, V))
+    scale = 1 + np.abs(want).max()
+    np.testing.assert_allclose(got["pallas"], got["vmap_l2"], rtol=1e-5,
+                               atol=1e-5 * scale)
+    np.testing.assert_allclose(got["pallas"], want, rtol=1e-4,
+                               atol=1e-4 * scale)
+
+
+def test_launch_shape_recorded_once_per_compiled_plan():
+    """Building a pallas executable records its launch shape once in the
+    metrics registry (at trace time); calling it again records nothing."""
+    from repro import engine, obs
+    from repro.kernels.chess_hvp import kernel_grid
+    obs.reset()
+    m, n = 300, 8
+    p = engine.plan(testfns.rosenbrock, n, m=m, csize=2, backend="pallas",
+                    symmetric=True, blk_m=128)
+    A, V = (jnp.ones((m, n), jnp.float32),) * 2
+    for _ in range(3):
+        jax.block_until_ready(p.batched_hvp(A, V))
+    reg = obs.metrics_registry()
+    shape = f"m{m}_n{n}_c2_sym"
+    blocks, cells = kernel_grid(m, n, 2, 128, True)
+    assert reg.value("repro_chess_hvp_traces_total", shape=shape) == 1
+    assert reg.value("repro_chess_hvp_grid_steps", shape=shape) == \
+        blocks * cells
+    assert reg.value("repro_chess_hvp_block_width", shape=shape) == 128
+    assert reg.value("repro_chess_hvp_lanes_filled_pct", shape=shape) == \
+        pytest.approx(100.0 * 300 / 384)
+
+
+# ---------------------------------------------------------------------------
 # shapes and modes the chip's compiler takes
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("m,blk_m,want", [
-    (500_000, 8, 8), (500_000, 4, 8), (500_000, 9, 16), (500_000, 16, 16),
-    (5, 8, 5), (3, 16, 3), (24, 1, 8),
+    (500_000, 8, 128), (500_000, 128, 128), (500_000, 129, 256),
+    (500_000, 1024, 1024), (5, 8, 5), (3, 16, 3), (300, 1, 128),
+    (200, 129, 200),
 ])
 def test_block_rows_heights_mosaic_takes(m, blk_m, want):
-    """A block is a multiple of the 8-row sublane tile or all of m."""
+    """A block is a multiple of the 128-lane tile or all of m."""
     assert block_rows(m, blk_m) == want
 
 

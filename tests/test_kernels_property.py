@@ -18,10 +18,10 @@ from repro.kernels.ref import chess_hvp_ref  # noqa: E402
 
 @settings(max_examples=12, deadline=None)
 @given(
-    m=st.integers(1, 9),
+    m=st.integers(1, 300),                 # one or several lane blocks
     n=st.integers(2, 12),
     csize=st.integers(1, 14),
-    blk_m=st.sampled_from([8, 16]),        # block heights Mosaic takes
+    blk_m=st.sampled_from([128, 256]),     # block widths Mosaic takes
     symmetric=st.booleans(),
     fname=st.sampled_from(["rosenbrock", "fletcher_powell"]),
     seed=st.integers(0, 2**16),

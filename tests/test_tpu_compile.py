@@ -1,6 +1,9 @@
 """chess_hvp compiled with Mosaic for a described TPU v5e, at the paper's
 width: n=16, m=500,000 instances, csize=4 for the three section-7
-functions and both schedules, plus one ragged chunking (csize=5).
+functions and both schedules, one ragged chunking (csize=5), the batch
+benchmark's own choice (csize=2, symmetric, the default block width), and
+n=64 at the narrowest and widest block widths the tuner sweeps, and an f
+whose operands differ in rank.
 
 Nothing runs: the TPU compiler, installed with jax, compiles for a chip
 that is described and not attached, and refuses what the chip would.  The
@@ -46,14 +49,15 @@ def one_chip(topo):
     jax.config.update("jax_enable_compilation_cache", was)
 
 
-def _compiled_hlo(one_chip, fname: str, csize: int, symmetric: bool) -> str:
-    kf, consts = kernel_form(testfns.FUNCTIONS[fname](N))
+def _compiled_hlo(one_chip, fname: str, csize: int, symmetric: bool,
+                  n: int = N, **kw) -> str:
+    kf, consts = kernel_form(testfns.FUNCTIONS[fname](n))
 
     def run(A, V, *cs):
         return chess_hvp_pallas(kf, A, V, csize, consts=cs,
-                                symmetric=symmetric, interpret=False)
+                                symmetric=symmetric, interpret=False, **kw)
 
-    x = jax.ShapeDtypeStruct((M, N), jnp.float32, sharding=one_chip)
+    x = jax.ShapeDtypeStruct((M, n), jnp.float32, sharding=one_chip)
     cs = [jax.ShapeDtypeStruct(c.shape, c.dtype, sharding=one_chip)
           for c in consts]
     return jax.jit(run).lower(x, x, *cs).compile().as_text()
@@ -69,3 +73,37 @@ def test_chess_hvp_compiles_for_v5e(one_chip, fname, symmetric):
 def test_chess_hvp_ragged_chunk_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in _compiled_hlo(one_chip, "fletcher_powell", 5,
                                               True)
+
+
+@pytest.mark.parametrize("fname", ["rosenbrock", "fletcher_powell"])
+def test_batch_cells_choice_compiles_for_v5e(one_chip, fname):
+    """The batch cells' plan: csize 2, symmetric, the default block."""
+    assert "tpu_custom_call" in _compiled_hlo(one_chip, fname, 2, True)
+
+
+@pytest.mark.parametrize("blk_m", [128, 1024])
+def test_n64_block_widths_fit_vmem(one_chip, blk_m):
+    """At n=64 (csize 4, the plan's choice) the default block and the
+    widest block the tuner sweeps both fit the scoped VMEM: Mosaic refuses
+    a kernel that does not."""
+    assert "tpu_custom_call" in _compiled_hlo(one_chip, "fletcher_powell", 4,
+                                              True, n=64, blk_m=blk_m)
+
+
+@pytest.mark.parametrize("n,csize", [(N, 2), (4, 4)])
+def test_mixed_rank_f_compiles_for_v5e(one_chip, n, csize):
+    """An f that multiplies the vector by a row and a reduction of itself:
+    the chunk-leading hDual's unit axes for lower-rank operands lower."""
+    import repro.core.hmath as hm
+
+    def f(x):
+        s = x.sum(0)
+        y = x * x[0] + hm.where(x[0] > 0.0, x, s) * s
+        return (y * y).sum(0)
+
+    def run(A, V):
+        return chess_hvp_pallas(f, A, V, csize, symmetric=True,
+                                interpret=False)
+
+    x = jax.ShapeDtypeStruct((4096, n), jnp.float32, sharding=one_chip)
+    assert "tpu_custom_call" in jax.jit(run).lower(x, x).compile().as_text()
