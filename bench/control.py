@@ -4,12 +4,12 @@
 
 The control is the reference put in the program's place, computed one
 precision below the configuration's float32: bfloat16, on the device.  For
-each seed it makes the cell's inputs as its driver does (a batch cell's
-input sets, a served cell's request pool), answers them with the control,
-and prints the number the cell compares -- the widest per-row gap to the
-float64 reference -- beside the cell's limit.  The benchmark's own runs
-never run it; ``tests/bench/test_bench_control.py`` keeps it at a size a
-test can hold.
+each seed the cell's driver module (``control_reading`` in
+``bench/<driver>.py``) makes the cell's inputs as its ``Driver`` does,
+answers them with the control, and gives the number the cell compares --
+the widest per-row gap to the float64 reference -- which is printed beside
+the cell's limit.  The benchmark's own runs never run it;
+``tests/bench/test_bench_control.py`` keeps it at a size a test can hold.
 """
 
 from __future__ import annotations
@@ -23,40 +23,18 @@ from . import run
 
 def control_reading(workload: str, seed: int, dtype: str = "bfloat16",
                     config_override: dict | None = None,
-                    traffic_override: dict | None = None) -> dict:
-    import numpy as np
-    from . import batch, reference, served
-    from .common import device_key, host_rng
-    bench = run.load_benchmark()
+                    traffic_override: dict | None = None,
+                    root: str = run.ROOT) -> dict:
+    bench = run.load_benchmark(root)
     cell = run.find_workload(bench, workload)
-    config = run.load_config(bench, cell["config"])
+    config = run.load_config(bench, cell["config"], root)
     config.spec.update(config_override or {})
-    traffic = {**run.load_traffic(cell["traffic"]),
+    traffic = {**run.load_traffic(cell["traffic"], root),
                **(traffic_override or {})}
-    groups = []             # (n, A, V) answered by the control
-    if traffic["driver"] == "batch":
-        n, m = config.spec["n"], config.spec["m"]
-        A, V = batch._inputs(device_key(seed), int(traffic["input_sets"]),
-                             m, n, float(traffic["a_low"]),
-                             float(traffic["a_high"]))
-        groups = [(n, np.asarray(A[s]), np.asarray(V[s]))
-                  for s in range(A.shape[0])]
-    else:
-        widths, pool = served._pool(
-            host_rng(seed, 1), traffic["n_mix"], int(traffic["pool"]),
-            float(traffic["a_low"]), float(traffic["a_high"]))
-        for n in sorted(set(int(w) for w in widths)):
-            rows = [p for p, w in zip(pool, widths) if w == n]
-            groups.append((n, np.stack([a for a, _ in rows]),
-                           np.stack([v for _, v in rows])))
-    worst = 0.0
-    for n, A, V in groups:
-        f = config.module.formula(n)
-        low = reference.hvp_in(f, A, V, dtype)
-        ref = reference.hvp_float64(f, A, V)
-        worst = max(worst, float(reference.row_rel_err(low, ref).max()))
+    value = run.driver_module(traffic, root).control_reading(
+        config, traffic, seed, dtype)
     return {"workload": workload, "seed": seed, "dtype": dtype,
-            "value": worst, "limit": float(config.spec["max_rel_err"])}
+            "value": value, "limit": float(config.spec["max_rel_err"])}
 
 
 def main(argv=None) -> int:

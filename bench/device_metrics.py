@@ -4,9 +4,9 @@ from __future__ import annotations
 
 from . import trace as tr
 from .peaks import peaks_for
-from .work import hvp_work, roofline_s
+from .work import Work, hvp_work, roofline_s
 
-__all__ = ["idle_pct", "roofline_pct"]
+__all__ = ["idle_pct", "share_of_roofline", "roofline_pct", "peak_share_pct"]
 
 
 def idle_pct(run):
@@ -19,21 +19,50 @@ def idle_pct(run):
     return 100.0 * (1.0 - sum(busy) / len(busy) / (hi - lo))
 
 
-def roofline_pct(run, span: str, n: int, m: int):
-    """The roofline time of the calls under host span ``span`` over the
-    device time of every operation inside them, in %; None where there is
-    no such call or no device time."""
+def share_of_roofline(run, span: str, work: Work, calls: int | None = None):
+    """The roofline time of ``work`` per call, over the device time of
+    every operation inside the host spans named ``span``, in %; None where
+    there is no such span or no device time.  One span per call, unless
+    ``calls`` counts the calls that the spans hold: work dispatched ahead
+    runs after the span that sent it, so its reader passes the window's
+    span and the count of calls in it."""
+    if run.timeline is None:
+        return None
+    spans = run.timeline.spans_named(span)
+    ops = run.timeline.all_ops()
+    if not spans or not ops:
+        return None
+    device_ns = tr.busy_ns(tr.within(ops, spans), spans[0][0], spans[-1][1])
+    if device_ns <= 0:
+        return None
+    calls = len(spans) if calls is None else calls
+    least_s, bound = roofline_s(work, peaks_for(run.device_kind))
+    return {"value": 100.0 * calls * least_s / (device_ns * 1e-9),
+            "bound": bound, "calls": calls, "device_s": device_ns * 1e-9}
+
+
+def roofline_pct(run, span: str, n: int, m: int, calls: int | None = None):
+    """``share_of_roofline`` of a batched HVP at (m, n) of the
+    configuration's formula (``bench.work.hvp_work``)."""
+    if run.timeline is None or not run.timeline.spans_named(span):
+        return None
+    return share_of_roofline(run, span, hvp_work(run.config.module.formula(n),
+                                                 m, n), calls)
+
+
+def peak_share_pct(run, span: str, flops_per_call: float):
+    """The whole step's share of the chip's peak, in %: the operations of
+    the calls under host span ``span`` over their wall time at the peak
+    FLOP/s of ``bench/peaks.py``,
+    100 x calls x flops_per_call / (wall time x peak).  None where there is
+    no such call."""
     if run.timeline is None:
         return None
     calls = run.timeline.spans_named(span)
-    ops = run.timeline.all_ops()
-    if not calls or not ops:
+    wall_ns = sum(t1 - t0 for t0, t1 in calls)
+    if not calls or wall_ns <= 0:
         return None
-    device_ns = tr.busy_ns(tr.within(ops, calls), calls[0][0], calls[-1][1])
-    if device_ns <= 0:
-        return None
-    work = hvp_work(run.config.module.formula(n), m, n)
-    least_s, bound = roofline_s(work, peaks_for(run.device_kind))
-    return {"value": 100.0 * len(calls) * least_s / (device_ns * 1e-9),
-            "bound": bound, "calls": len(calls),
-            "device_s": device_ns * 1e-9}
+    peak = peaks_for(run.device_kind).flops
+    return {"value": 100.0 * len(calls) * flops_per_call
+            / (wall_ns * 1e-9 * peak),
+            "calls": len(calls), "wall_s": wall_ns * 1e-9}

@@ -14,7 +14,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["hvp_float64", "hvp_in", "row_rel_err", "BLOCK_ROWS"]
+__all__ = ["hvp_float64", "hvp_in", "row_rel_err", "widest_gap",
+           "BLOCK_ROWS"]
 
 BLOCK_ROWS = 65536
 
@@ -69,3 +70,11 @@ def row_rel_err(got, want) -> np.ndarray:
     err = gap / scale
     err[~np.all(np.isfinite(got), axis=-1)] = np.inf
     return err
+
+
+def widest_gap(formula, A, V, dtype: str) -> float:
+    """The number a control reads: the widest per-row gap of the HVPs of
+    rows (A, V) computed in ``dtype`` to the float64 reference."""
+    low = hvp_in(formula, A, V, dtype)
+    ref = hvp_float64(formula, A, V)
+    return float(row_rel_err(low, ref).max())

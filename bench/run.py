@@ -33,6 +33,8 @@ BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH_DIR)
 NO_CHIP_EXIT = 3
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# a key of a configuration that ends so is a width, which no cut may change
+WIDTH_SUFFIXES = ("_dim", "_rank")
 
 
 def process_age_s() -> float:
@@ -81,11 +83,64 @@ def find_workload(bench: dict, name: str) -> dict:
                      f"{[w['name'] for w in bench['workloads']]}")
 
 
+def check_config(entry: dict, spec: dict) -> None:
+    """The contract for a configuration's cuts; raises ValueError.
+
+    ``entry`` is the configuration's entry in BENCHMARK.json and ``spec``
+    its file.  Both list the same ``reduced`` keys.  Each of them is a key
+    of the file, no width (a key ending in ``_dim`` or ``_rank``), and
+    ``spec["published"]`` holds the value the source publishes for it,
+    which differs from the one held.  A configuration that is cut states
+    its ``deployment``: over how many chips each layer is divided, and how,
+    in one line of at most 200 characters.  Every name in ``assumed`` is a
+    key of the file."""
+    name = entry.get("name")
+    reduced = entry["reduced"]
+    if not (isinstance(reduced, list)
+            and all(isinstance(k, str) for k in reduced)):
+        raise ValueError(f"{name}: reduced is not a list of key names")
+    if spec.get("reduced") != reduced:
+        raise ValueError(f"{name}: reduced {reduced} in BENCHMARK.json, "
+                         f"{spec.get('reduced')} in its file")
+    published = spec.get("published", {})
+    for k in reduced:
+        if k not in spec:
+            raise ValueError(f"{name}: reduced key {k!r} is not in its file")
+        if k.endswith(WIDTH_SUFFIXES):
+            raise ValueError(f"{name}: {k!r} is a width, never cut")
+        if k not in published:
+            raise ValueError(f"{name}: no published value of {k!r}")
+        if published[k] == spec[k]:
+            raise ValueError(f"{name}: {k!r} is as published, not cut")
+    if reduced:
+        dep = spec.get("deployment")
+        if not (isinstance(dep, str) and 1 <= len(dep) <= 200
+                and "\n" not in dep):
+            raise ValueError(f"{name}: a cut configuration states its "
+                             f"deployment in one line of 1-200 characters")
+    for k in spec.get("assumed", []):
+        if k not in spec:
+            raise ValueError(f"{name}: assumed key {k!r} is not in its file")
+
+
+def check_chips(workloads: list) -> None:
+    """Every cell takes 1 or 4 chips, and at most half of the cells,
+    rounded down, take 4 (one always may); raises ValueError."""
+    for w in workloads:
+        if w["chips"] not in (1, 4):
+            raise ValueError(f"{w['name']}: chips {w['chips']!r}, not 1 or 4")
+    four = sum(w["chips"] == 4 for w in workloads)
+    if four > max(1, len(workloads) // 2):
+        raise ValueError(f"{four} of {len(workloads)} cells take 4 chips; "
+                         f"at most {max(1, len(workloads) // 2)} may")
+
+
 def load_config(bench: dict, name: str, root: str = ROOT) -> Config:
     entry = next(c for c in bench["configs"] if c["name"] == name)
     path = os.path.join(root, entry["file"])
     with open(path) as f:
         spec = json.load(f)
+    check_config(entry, spec)
     module = _load_module(os.path.splitext(path)[0] + ".py",
                           f"bench_config_{name}")
     return Config(name, spec, module)
@@ -96,8 +151,20 @@ def load_traffic(name: str, root: str = ROOT) -> dict:
         return json.load(f)
 
 
-def load_driver(traffic: dict):
-    return importlib.import_module(f"bench.{traffic['driver']}").Driver
+def driver_module(traffic: dict, root: str = ROOT):
+    """The module of the traffic's general driver, ``bench/<driver>.py``:
+    its ``Driver``, its ``control_reading`` and its test sizes ``SMALL``.
+    One that only a copy of the benchmark at ``root`` has is loaded from
+    its file there."""
+    file = f"{traffic['driver']}.py"
+    if os.path.isfile(os.path.join(BENCH_DIR, file)):
+        return importlib.import_module(f"bench.{traffic['driver']}")
+    return _load_module(os.path.join(root, "bench", file),
+                        f"bench.{traffic['driver']}")
+
+
+def load_driver(traffic: dict, root: str = ROOT):
+    return driver_module(traffic, root).Driver
 
 
 def load_reader(metric: str, root: str = ROOT):
@@ -151,6 +218,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     the harness without a chip, without the persistent compile cache and
     at sizes a test can hold; a run of the benchmark never passes them."""
     bench = load_benchmark(root)
+    check_chips(bench["workloads"])
     cell = find_workload(bench, workload)
     config = load_config(bench, cell["config"], root)
     config.spec.update(config_override or {})
@@ -161,6 +229,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     from . import trace as tr
     from .common import memory_peak_bytes
 
+    # a cell on 4 chips takes the first 4 devices: the line reports 4, and
+    # memory_peak_bytes reads the fullest of them
     devices = _devices_or_none(int(cell["chips"]), on_chip)
     if devices is None:
         found = jax.devices()
@@ -175,7 +245,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     jax.monitoring.register_event_duration_secs_listener(events.on_duration)
     trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
     try:
-        driver = load_driver(traffic)(config, traffic, seed)
+        driver = load_driver(traffic, root)(config, traffic, seed)
         setup_events = dict(events.counts)
         setup_s = process_age_s()
         if trace:

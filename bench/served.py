@@ -26,7 +26,8 @@ Traffic parameters (``bench/traffic/<name>.json``, ``"driver": "served"``):
 The CPU tests may add ``server``, knobs that override ``SERVER_DEFAULTS``,
 to run at sizes a test can hold; no traffic file of a cell sets it.
 
-Every reply of the window is compared with the float64 reference.
+Every reply of the window is compared with the float64 reference;
+``control_reading`` gives the control of that comparison on the same pool.
 """
 
 from __future__ import annotations
@@ -41,7 +42,7 @@ from . import reference
 from .arrivals import OpenLoop, poisson_arrivals
 from .common import Checked, host_rng
 
-__all__ = ["Driver"]
+__all__ = ["Driver", "control_reading", "SMALL"]
 
 DRAIN_S = 60.0          # how long a reply may come after the window closes
 TRACE_CAPACITY = 1_000_000
@@ -50,6 +51,11 @@ TRACE_CAPACITY = 1_000_000
 # no admission controller)
 SERVER_DEFAULTS = {"max_batch": 64, "max_wait_us": 500.0, "max_queue": 4096,
                    "coalesce_across_n": True, "coalesce_waste_max": 0.4}
+# the overrides that run a cell of this driver at a size a CPU test can hold
+SMALL = {"config": {},
+         "traffic": {"n_mix": {"8": 0.5, "12": 0.5}, "pool": 128,
+                     "warm_seconds": 0.2, "callers": 8, "rate_rps": 200.0,
+                     "server": {"max_batch": 4, "max_wait_us": 500.0}}}
 
 
 def _pool(rng, n_mix: dict, size: int, a_low: float, a_high: float):
@@ -311,3 +317,19 @@ class Driver:
                 res.failed += not err <= limit
         res.add("max_request_rel_err", worst, limit)
         return res
+
+
+def control_reading(config, traffic: dict, seed: int, dtype: str) -> float:
+    """The control on the cell's request pool: the reference computed in
+    ``dtype`` in the program's place; its widest per-row gap."""
+    widths, pool = _pool(
+        host_rng(seed, 1), traffic["n_mix"], int(traffic["pool"]),
+        float(traffic["a_low"]), float(traffic["a_high"]))
+    worst = 0.0
+    for n in sorted(set(int(w) for w in widths)):
+        rows = [p for p, w in zip(pool, widths) if w == n]
+        A = np.stack([a for a, _ in rows])
+        V = np.stack([v for _, v in rows])
+        worst = max(worst, reference.widest_gap(config.module.formula(n),
+                                                A, V, dtype))
+    return worst

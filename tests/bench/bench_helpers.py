@@ -12,24 +12,25 @@ for p in (ROOT, os.path.join(ROOT, "src")):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-from bench import run  # noqa: E402
+from bench import batch, run, served  # noqa: E402
 
-SMALL_M = 256
-SMALL_SERVED = {"n_mix": {"8": 0.5, "12": 0.5}, "pool": 128,
-                "warm_seconds": 0.2, "callers": 8, "rate_rps": 200.0,
-                "server": {"max_batch": 4, "max_wait_us": 500.0}}
+# the test sizes of the two drivers, by the names the tests import
+SMALL_M = batch.SMALL["config"]["m"]
+SMALL_SERVED = served.SMALL["traffic"]
 
 
 def run_small(workload: str, seed: int = 12345, seconds: float = 0.5,
               trace: bool = False, root: str = ROOT, **kw):
-    """One CPU run of a cell through the harness at a test size.  The
-    execution telemetry it leaves behind is cleared, so it cannot steer
-    ``backend="auto"`` in tests that run later in the same process."""
+    """One CPU run of a cell through the harness at its driver's test size
+    (``SMALL`` of the driver module).  The execution telemetry it leaves
+    behind is cleared, so it cannot steer ``backend="auto"`` in tests that
+    run later in the same process."""
     from repro import engine
-    if ".batch" in workload:
-        kw.setdefault("config_override", {"m": SMALL_M})
-    else:
-        kw.setdefault("traffic_override", SMALL_SERVED)
+    cell = run.find_workload(run.load_benchmark(root), workload)
+    small = run.driver_module(run.load_traffic(cell["traffic"], root),
+                              root).SMALL
+    kw.setdefault("config_override", small["config"])
+    kw.setdefault("traffic_override", small["traffic"])
     try:
         return run.run_cell(workload, seed, seconds, trace, root=root,
                             on_chip=False, **kw)

@@ -16,51 +16,55 @@ BENCH = run.load_benchmark()
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 
 
-def test_top_level_keys():
-    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
+# The schema tests take the benchmark and its root as defaults, so that a
+# test of a copy (tests/bench/test_bench_new_cell.py) runs them on it.
+
+def test_top_level_keys(bench=BENCH, root=ROOT):
+    assert set(bench) == {"command", "paths", "run_seconds", "configs",
                           "workloads", "end_to_end", "per_layer"}
-    assert BENCH["command"][:3] == ["python3", "-m", "bench.run"]
-    assert 1 <= BENCH["run_seconds"] <= 51
-    assert isinstance(BENCH["run_seconds"], int)
-    for p in BENCH["paths"]:
-        assert os.path.isdir(os.path.join(ROOT, p))
-    assert len(json.dumps(BENCH)) < 64 * 1024
+    assert bench["command"][:3] == ["python3", "-m", "bench.run"]
+    assert 1 <= bench["run_seconds"] <= 51
+    assert isinstance(bench["run_seconds"], int)
+    for p in bench["paths"]:
+        assert os.path.isdir(os.path.join(root, p))
+    assert len(json.dumps(bench)) < 64 * 1024
 
 
-def test_configs():
-    names = [c["name"] for c in BENCH["configs"]]
+def test_configs(bench=BENCH, root=ROOT):
+    names = [c["name"] for c in bench["configs"]]
     assert len(set(names)) == len(names)
-    used = {w["config"] for w in BENCH["workloads"]}
-    for c in BENCH["configs"]:
+    used = {w["config"] for w in bench["workloads"]}
+    for c in bench["configs"]:
         assert set(c) == {"name", "source", "file", "reduced", "why"}
         assert NAME.match(c["name"]) and c["name"] in used
-        assert c["file"].startswith(tuple(p + "/" for p in BENCH["paths"]))
-        with open(os.path.join(ROOT, c["file"])) as f:
+        assert c["file"].startswith(tuple(p + "/" for p in bench["paths"]))
+        with open(os.path.join(root, c["file"])) as f:
             spec = json.load(f)
         assert spec["name"] == c["name"]
-        assert spec["reduced"] == c["reduced"] == []
+        run.check_config(c, spec)
+        assert spec["reduced"] == c["reduced"]
         assert os.path.isfile(os.path.splitext(
-            os.path.join(ROOT, c["file"]))[0] + ".py")
+            os.path.join(root, c["file"]))[0] + ".py")
 
 
-def test_workloads_find_their_files():
+def test_workloads_find_their_files(bench=BENCH, root=ROOT):
     seen = set()
-    for w in BENCH["workloads"]:
+    run.check_chips(bench["workloads"])
+    for w in bench["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
         assert NAME.match(w["name"]) and NAME.match(w["traffic"])
         assert (w["config"], w["traffic"]) not in seen
         seen.add((w["config"], w["traffic"]))
-        assert w["chips"] == 1
         assert 1 <= len(w["why"]) <= 200 and "\n" not in w["why"]
-        traffic = run.load_traffic(w["traffic"])
-        assert run.load_driver(traffic).__name__ == "Driver"
-        run.load_config(BENCH, w["config"])
+        traffic = run.load_traffic(w["traffic"], root)
+        assert run.load_driver(traffic, root).__name__ == "Driver"
+        run.load_config(bench, w["config"], root)
 
 
-def test_metrics():
-    e2e = {m["name"]: m for m in BENCH["end_to_end"]}
-    cells = {w["name"] for w in BENCH["workloads"]}
-    for m in BENCH["end_to_end"]:
+def test_metrics(bench=BENCH, root=ROOT):
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    cells = {w["name"] for w in bench["workloads"]}
+    for m in bench["end_to_end"]:
         assert set(m) <= {"name", "unit", "better", "bound", "source",
                           "workloads"}
         assert m["source"] in {"host_clock", "device_trace"}
@@ -68,24 +72,81 @@ def test_metrics():
         assert set(m.get("workloads", cells)) <= cells
     assert e2e["setup_s"]["bound"] <= 0.25 and "workloads" not in e2e[
         "setup_s"]
-    for m in BENCH["per_layer"]:
+    for m in bench["per_layer"]:
         assert set(m) <= {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
         assert m["source"] in SOURCES and m["moves"] in e2e
         moved = set(e2e[m["moves"]].get("workloads", cells))
         assert set(m["workloads"]) <= moved
-        assert callable(run.load_reader(m["name"]))
-    for m in BENCH["end_to_end"] + BENCH["per_layer"]:
+        assert callable(run.load_reader(m["name"], root))
+    for m in bench["end_to_end"] + bench["per_layer"]:
         assert NAME.match(m["name"]) and UNIT.match(m["unit"])
         assert m["better"] in ("lower", "higher")
 
 
-def test_every_cell_reports_setup_another_e2e_and_a_layer():
-    for w in BENCH["workloads"]:
-        e2e = {m["name"] for m in run.metrics_of(BENCH, w["name"],
+def test_every_cell_reports_setup_another_e2e_and_a_layer(bench=BENCH):
+    for w in bench["workloads"]:
+        e2e = {m["name"] for m in run.metrics_of(bench, w["name"],
                                                  "end_to_end")}
         assert "setup_s" in e2e and len(e2e) >= 2
-        assert run.metrics_of(BENCH, w["name"], "per_layer")
+        assert run.metrics_of(bench, w["name"], "per_layer")
+
+
+def _cut(entry_reduced=("num_layers",), **over):
+    """The entry and the file of a configuration cut in depth, with
+    ``over`` laid over the file."""
+    spec = {"name": "cut", "num_layers": 2, "hidden_size": 64,
+            "head_dim": 16, "reduced": ["num_layers"],
+            "published": {"num_layers": 8, "head_dim": 32},
+            "deployment": "each layer whole on one chip; the 8 layers as "
+                          "4 pipeline stages of 2, this chip holds one",
+            "assumed": ["hidden_size"], **over}
+    return {"name": "cut", "reduced": list(entry_reduced)}, spec
+
+
+def _existing(name):
+    entry = next(c for c in BENCH["configs"] if c["name"] == name)
+    with open(os.path.join(ROOT, entry["file"])) as f:
+        return entry, json.load(f)
+
+
+def _cells(*chips):
+    return ([{"name": f"c{i}", "chips": c} for i, c in enumerate(chips)],)
+
+
+# case -> (contract function, its arguments, whether it refuses them)
+CONTRACT = {
+    "no-published-value": (run.check_config,
+                           lambda: _cut(published={}), True),
+    "published-as-held": (run.check_config,
+                          lambda: _cut(published={"num_layers": 2}), True),
+    "reduced-differs-from-file": (run.check_config,
+                                  lambda: _cut(entry_reduced=()), True),
+    "cut-without-deployment": (run.check_config,
+                               lambda: _cut(deployment=None), True),
+    "width-cut": (run.check_config,
+                  lambda: _cut(("head_dim",), reduced=["head_dim"]), True),
+    "5-cells-3-on-4-chips": (run.check_chips,
+                             lambda: _cells(1, 4, 1, 4, 4), True),
+    "cut-as-stated": (run.check_config, _cut, False),
+    "1-cell-on-4-chips": (run.check_chips, lambda: _cells(4), False),
+    "4-cells-2-on-4-chips": (run.check_chips, lambda: _cells(1, 4, 4, 1),
+                             False),
+    "rosenbrock": (run.check_config, lambda: _existing("rosenbrock"),
+                   False),
+    "fletcher_powell": (run.check_config,
+                        lambda: _existing("fletcher_powell"), False),
+}
+
+
+@pytest.mark.parametrize("case", list(CONTRACT))
+def test_cut_and_chip_contract(case):
+    check, args, refused = CONTRACT[case]
+    if refused:
+        with pytest.raises(ValueError):
+            check(*args())
+    else:
+        check(*args())
 
 
 def test_check_budget_fits():
@@ -119,7 +180,8 @@ def test_a_new_cell_needs_only_new_files_and_an_entry(tmp_path):
     with open(os.path.join(root, "bench", "traffic", "batch-one-set.json"),
               "w") as f:
         json.dump({"driver": "batch", "input_sets": 1, "a_low": -1.0,
-                   "a_high": 1.0}, f)
+                   "a_high": 1.0, "lead_s": 0.05, "lead_bytes": 1e8,
+                   "checks": 10**6}, f)
     with open(os.path.join(root, "bench", "metrics", "calls_made.py"),
               "w") as f:
         f.write("def read(run):\n    return run.counters['calls']\n")

@@ -84,6 +84,27 @@ def test_roofline_share_on_a_synthetic_trace():
     assert got["value"] == pytest.approx(100 * 2 * least_s / 1.4e-3)
 
 
+def test_hvp_roofline_counts_calls_that_run_after_their_dispatch():
+    """Calls dispatched ahead run after the short host span that sent them:
+    the reader takes the device time of the whole window and the window's
+    count of calls."""
+    m, n = 64, 16
+    config = run.load_config(run.load_benchmark(), "rosenbrock")
+    config.spec = {**config.spec, "m": m}
+    ops = [("chess_hvp", 100, 900_100), ("copy", 2_000_000, 2_500_000)]
+    host = [("bench:window", 0, 3_000_000), ("bench:batched_hvp", 0, 50),
+            ("bench:batched_hvp", 60, 90)]
+    view = run.RunView(None, config, {"calls": 2},
+                       tr.Timeline(ops={"/device:TPU:0": ops}, host=host),
+                       (0, 3_000_000), "TPU v5 lite")
+    got = run.load_reader("hvp_roofline")(view)
+    least_s, _ = roofline_s(hvp_work(config.module.formula(n), m, n),
+                            peaks_for("TPU v5 lite"))
+    assert got["calls"] == 2
+    assert got["device_s"] == pytest.approx(1.4e-3)
+    assert got["value"] == pytest.approx(100 * 2 * least_s / 1.4e-3)
+
+
 def test_load_reads_a_recorded_trace(tmp_path):
     f = jax.jit(lambda x: jnp.sin(x) @ x)
     x = jnp.ones((64, 64))
